@@ -132,7 +132,7 @@ def packed_census_bytes(cfg, trainer: str, n_data: int = 16, n_pod: int = 1) -> 
     import jax.numpy as jnp
 
     from repro.analysis.jaxpr_audit import collective_census
-    from repro.dist import compat
+    from repro.launch.mesh import make_mesh
     from repro.dist.collectives import PackedVoteWire
     from repro.kernels import common as kcommon
     from repro.launch.mesh import make_host_mesh
@@ -145,9 +145,9 @@ def packed_census_bytes(cfg, trainer: str, n_data: int = 16, n_pod: int = 1) -> 
     for n, count in exchange_sizes(cfg, trainer).items():
         packed = jax.ShapeDtypeStruct(
             (kcommon.canonical_rows(n), kcommon.LANES // 4), jnp.uint8)
-        fn = compat.shard_map(lambda p, n=n: wire.exchange(p, n, (n,)),
-                              mesh=mesh, in_specs=P(), out_specs=P(),
-                              check_vma=False)
+        fn = jax.shard_map(lambda p, n=n: wire.exchange(p, n, (n,)),
+                           mesh=mesh, in_specs=P(), out_specs=P(),
+                           check_vma=False)
         census = collective_census(jax.make_jaxpr(fn)(packed))
         total += census.total_bytes({"data": m}) * count
     return total
@@ -208,7 +208,8 @@ def bucketed_census_bytes(cfg, trainer: str, n_data: int = 16,
     import jax.numpy as jnp
 
     from repro.analysis.jaxpr_audit import collective_census
-    from repro.dist import bucketing, compat
+    from repro.dist import bucketing
+    from repro.launch.mesh import make_mesh
     from repro.dist.collectives import PackedVoteWire
     from repro.launch.mesh import make_host_mesh
 
@@ -227,7 +228,7 @@ def bucketed_census_bytes(cfg, trainer: str, n_data: int = 16,
             buf = jax.ShapeDtypeStruct(
                 (b.rows, bucketing.ROW_WIDTH[plan.fmt]),
                 bucketing.ROW_DTYPE[plan.fmt])
-            fn = compat.shard_map(
+            fn = jax.shard_map(
                 lambda p, b=b: wire.exchange_bucket(p, b),
                 mesh=mesh, in_specs=P(), out_specs=[P()] * len(b.slots),
                 check_vma=False)
@@ -285,7 +286,7 @@ def ring_census_bytes(cfg, trainer: str, n_data: int = 16,
     import jax.numpy as jnp
 
     from repro.analysis.jaxpr_audit import collective_census
-    from repro.dist import compat
+    from repro.launch.mesh import make_mesh
     from repro.dist.collectives import PackedVoteWire
     from repro.kernels import common as kcommon
     from repro.launch.mesh import make_host_mesh
@@ -300,9 +301,9 @@ def ring_census_bytes(cfg, trainer: str, n_data: int = 16,
         wire = PackedVoteWire(axes=("data",), n_workers=m,
                               backend="interpret", ring_chunk_rows=chunk)
         packed = jax.ShapeDtypeStruct((rows, kcommon.LANES // 4), jnp.uint8)
-        fn = compat.shard_map(lambda p, n=n, w=wire: w.exchange(p, n, (n,)),
-                              mesh=mesh, in_specs=P(), out_specs=P(),
-                              check_vma=False)
+        fn = jax.shard_map(lambda p, n=n, w=wire: w.exchange(p, n, (n,)),
+                           mesh=mesh, in_specs=P(), out_specs=P(),
+                           check_vma=False)
         census = collective_census(jax.make_jaxpr(fn)(packed))
         total += census.total_bytes({"data": m}) * count
     return total
@@ -343,13 +344,13 @@ def _time_simple_steps(modes, records, repeats: int):
     import jax
 
     from repro.analysis import drivers
-    from repro.dist import compat
+    from repro.launch.mesh import make_mesh
 
     for mode in modes:
         for bucketed in (False, True):
             step, state, batch, model, mesh, _ = drivers.build_mode_step(
                 mode, bucketed=bucketed)
-            with compat.set_mesh(mesh):
+            with jax.sharding.set_mesh(mesh):
                 (_, metrics), dt = timed(
                     lambda: jax.block_until_ready(step(state, batch)),
                     repeats=repeats)
@@ -373,7 +374,7 @@ def _time_elastic_steps(records, repeats: int):
     import jax
 
     from repro.analysis import drivers
-    from repro.dist import compat
+    from repro.launch.mesh import make_mesh
     from repro.dist.collectives import ParticipationSpec
 
     for tag, part in (
@@ -381,7 +382,7 @@ def _time_elastic_steps(records, repeats: int):
             ("elastic_mask50", ParticipationSpec(q_frac=0.5, dropout=0.5))):
         step, state, batch, model, mesh, _ = drivers.build_mode_step(
             "votes", participation=part)
-        with compat.set_mesh(mesh):
+        with jax.sharding.set_mesh(mesh):
             (_, metrics), dt = timed(
                 lambda: jax.block_until_ready(step(state, batch)),
                 repeats=repeats)
@@ -406,7 +407,7 @@ def _time_streamed_steps(modes, records, repeats: int):
     from repro.analysis import drivers
     from repro.core.algorithm import CompressionConfig
     from repro.core.budgets import BudgetConfig
-    from repro.dist import compat
+    from repro.launch.mesh import make_mesh
     from repro.models.model import Model
     from repro.train.state import LrSchedule, init_state
     from repro.train.step_streamed import (StreamedStepConfig,
@@ -419,7 +420,7 @@ def _time_streamed_steps(modes, records, repeats: int):
               f"(have {n_dev})")
         return
     data = 4 if n_dev >= 8 else 2
-    mesh = compat.make_mesh((data, n_dev // data), ("data", "model"))
+    mesh = make_mesh((data, n_dev // data), ("data", "model"))
     cfg = get_config("qwen2-moe-a2.7b", smoke=True)
     model = Model(cfg)
     params = model.init(jax.random.PRNGKey(0))
@@ -448,7 +449,7 @@ def _time_streamed_steps(modes, records, repeats: int):
                 backend="jnp", bucketed=bucketed,
                 ring_chunk_rows=ring_rows), mesh)
             state = init_state(params, server=server, seed=42)
-            with compat.set_mesh(mesh):
+            with jax.sharding.set_mesh(mesh):
                 (_, metrics), dt = timed(
                     lambda: jax.block_until_ready(step(state, batch)),
                     repeats=repeats)

@@ -22,7 +22,7 @@ from repro.configs.base import LayerSpec, ModelConfig
 from repro.core.algorithm import CompressionConfig
 from repro.core.budgets import BudgetConfig
 from repro.data.synthetic import LMStreamConfig, lm_batch
-from repro.dist import compat
+from repro.launch.mesh import make_mesh
 from repro.models.model import Model
 from repro.train import loop as loop_lib
 from repro.train.state import LrSchedule, init_state
@@ -63,7 +63,7 @@ def main(argv=None):
     print(f"model: {cfg.name}, {n_params/1e6:.1f}M params; {steps} steps, "
           f"batch {args.batch} x seq {seq}")
 
-    mesh = compat.make_mesh((4, 2), ("data", "model"))
+    mesh = make_mesh((4, 2), ("data", "model"))
     comp = CompressionConfig(compressor="sparsign", budget=BudgetConfig(value=1.0),
                              server="scaled_sign_ef")
     step = build_train_step(model, TrainStepConfig(
@@ -78,7 +78,7 @@ def main(argv=None):
 
     lcfg = loop_lib.LoopConfig(total_steps=steps, ckpt_dir=args.ckpt_dir,
                                ckpt_every=max(10, steps // 5), log_every=max(1, steps // 20))
-    with compat.set_mesh(mesh):
+    with jax.sharding.set_mesh(mesh):
         state, history = loop_lib.run(step, state, batch_fn, lcfg)
     if not history:
         print(f"\nnothing to do: checkpoint in {args.ckpt_dir} is already at "

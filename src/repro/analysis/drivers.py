@@ -300,10 +300,9 @@ def elastic_mode_ledger(mode: str, model, m: int):
 def traced_step_census(mode: str, *, bucketed: bool = False):
     """Trace the mode's built step and census its collectives. Returns
     (census, model)."""
-    from repro.dist import compat
 
     step, state, batch, model, mesh, _ = build_mode_step(mode, bucketed=bucketed)
-    with compat.set_mesh(mesh):
+    with jax.sharding.set_mesh(mesh):
         closed = jax.make_jaxpr(step)(state, batch)
     return collective_census(closed), model
 
@@ -424,7 +423,6 @@ def run_participation_checks(m: int = HYPOTHETICAL_M):
     mask rule too: the chunked ppermute hop ships the same masked buffers,
     and the cross-scope backtrack (while-carry -> init operand) is exactly
     what the ring exercises."""
-    from repro.dist import compat
 
     findings, checks = [], 0
     census_rule = CollectiveCensus(axis_sizes={"data": m})
@@ -434,7 +432,7 @@ def run_participation_checks(m: int = HYPOTHETICAL_M):
         for bucketed in (False, True):
             step, state, batch, model, mesh, _ = build_mode_step(
                 mode, bucketed=bucketed, elastic=True)
-            with compat.set_mesh(mesh):
+            with jax.sharding.set_mesh(mesh):
                 closed = jax.make_jaxpr(step)(state, batch)
             census = collective_census(closed)
             label = f"step[{mode}{'/bucketed' if bucketed else ''}/elastic]"
@@ -457,7 +455,7 @@ def run_participation_checks(m: int = HYPOTHETICAL_M):
     for mode in RING_SETUPS:
         step, state, batch, model, mesh, _ = build_mode_step(mode,
                                                              bucketed=True)
-        with compat.set_mesh(mesh):
+        with jax.sharding.set_mesh(mesh):
             closed = jax.make_jaxpr(step)(state, batch)
         findings += mask_rule.check(f"step[{mode}/bucketed]", closed)
         checks += 1
@@ -623,10 +621,9 @@ def hlo_check(mode: str = "votes"):
     one device, where every ring term is zero on all three sides — degenerate
     but honest; the nonzero byte math of the HLO model is pinned by the
     synthetic-HLO tests in tests/test_analysis.py."""
-    from repro.dist import compat
 
     step, state, batch, model, mesh, _ = build_mode_step(mode)
-    with compat.set_mesh(mesh):
+    with jax.sharding.set_mesh(mesh):
         stats = hlo_collective_stats(step, state, batch, default_group=1)
         closed = jax.make_jaxpr(step)(state, batch)
     census = collective_census(closed)

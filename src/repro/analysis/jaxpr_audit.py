@@ -46,10 +46,7 @@ import jax.numpy as jnp
 
 from repro.analysis.framework import Finding, Rule
 
-try:
-    from jax.extend import core as jcore
-except ImportError:  # pragma: no cover — very old jax
-    from jax import core as jcore
+from jax.extend import core as jcore
 
 
 # ---------------------------------------------------------------------------

@@ -46,7 +46,6 @@ import jax.numpy as jnp
 from repro.core.budgets import BudgetConfig, resolve_budget
 from repro.core.compressors import (CompressedGrad, CompressorSpec,
                                     chunked_values, get_spec)
-from repro.dist import compat
 from repro.kernels import common as kcommon
 from repro.kernels.ef_server.ops import ef_server_op
 from repro.kernels.ef_server.ref import ef_server_ref
@@ -82,6 +81,18 @@ def resolve_backend(backend: Optional[str] = None) -> str:
     if b not in BACKENDS:
         raise ValueError(f"unknown kernel backend {b!r}; known: {('auto',) + BACKENDS}")
     return b
+
+
+def manual_axes(backend: str, mesh, worker_axes) -> set:
+    """The mesh axes a trainer's shard_map takes manual.
+
+    GSPMD cannot partition a compiled Mosaic kernel, so the pallas backend
+    takes every mesh axis manual; the axes beyond the worker axes then hold
+    full replicas instead of GSPMD's tensor-parallel split. The other
+    backends lower to XLA ops and leave those axes to GSPMD."""
+    if backend == "pallas":
+        return set(mesh.axis_names)
+    return set(worker_axes)
 
 
 def is_vote_server(cfg: "CompressionConfig") -> bool:
@@ -319,7 +330,7 @@ def compress_leaf(
     backend = resolve_backend(backend)
     spec: CompressorSpec = get_spec(cfg.compressor)
     if shared_linf is None and needs_shared_linf(cfg):
-        mapped = compat.manual_axis_names()
+        mapped = jax.sharding.get_abstract_mesh().manual_axes
         if mapped:
             raise ValueError(
                 f"compressor {cfg.compressor!r} needs the magnitude-shared "

@@ -1,6 +1,6 @@
 """repro.dist — the distributed substrate.
 
-Two modules plus a version bridge:
+Two modules:
 
 - ``sharding``:    logical-axis -> mesh PartitionSpec rules (TP/EP/SP for
   params, activations, decode caches) and the placement sanitizer that keeps
@@ -9,10 +9,8 @@ Two modules plus a version bridge:
   ternary messages, the server sums" step, as shard_map collectives in three
   wire-equivalent variants (flat int psum, hierarchical pod/data psum,
   2-bit-packed all-gather).
-- ``compat``:      feature-detecting bridge between the current jax sharding
-  API this repo targets and the pinned jax 0.4.x in the container.
 """
 
-from repro.dist import collectives, compat, sharding
+from repro.dist import collectives, sharding
 
-__all__ = ["collectives", "compat", "sharding"]
+__all__ = ["collectives", "sharding"]

@@ -69,7 +69,6 @@ from typing import Optional, Sequence, Tuple
 import jax
 import jax.numpy as jnp
 
-from repro.dist import compat
 
 VOTE_IMPLS = ("psum", "hier", "allgather_packed")
 
@@ -152,16 +151,11 @@ class ParticipationSpec:
         return q / float(n_workers)
 
 
-def axis_size(name) -> int:
-    """Static size of a named mesh axis (valid inside shard_map)."""
-    return compat.axis_size(name)
-
-
 def worker_count(axes: Sequence[str]) -> int:
     """M = product of the worker-axis sizes (static)."""
     n = 1
     for a in axes:
-        n *= compat.axis_size(a)
+        n *= jax.lax.axis_size(a)
     return n
 
 
@@ -170,7 +164,7 @@ def worker_index(axes: Sequence[str]) -> jnp.ndarray:
     idx = None
     for a in axes:
         i = jax.lax.axis_index(a)
-        idx = i if idx is None else idx * compat.axis_size(a) + i
+        idx = i if idx is None else idx * jax.lax.axis_size(a) + i
     return idx
 
 
@@ -552,27 +546,7 @@ def ring_permute(x: jnp.ndarray, axes: Sequence[str]) -> jnp.ndarray:
     wires' axis-0 stacking, so ring arrival order is a pure rotation of the
     monolithic gather's worker order."""
     axes = tuple(axes)
-    if len(axes) == 1:
-        return jax.lax.ppermute(x, axes[0],
-                                ring_perm(compat.axis_size(axes[0])))
-    if compat.HAS_TUPLE_PPERMUTE:
-        return jax.lax.ppermute(x, axes, ring_perm(worker_count(axes)))
-    return _ring_permute_nested(x, axes)
-
-
-def _ring_permute_nested(x: jnp.ndarray, axes: Tuple[str, ...]) -> jnp.ndarray:
-    """Old-jax fallback: compose single-axis ppermutes into the flat-product
-    ring shift. One hop of the flat ring advances the innermost axis; the
-    worker that wraps (innermost index 0 after the shift) must also take the
-    carry into the outer axes — everyone shifts the inner axis, the outer
-    shift is computed unconditionally (collectives can't branch per-device)
-    and selected only on the wrapping workers."""
-    s = compat.axis_size(axes[-1])
-    y = jax.lax.ppermute(x, axes[-1], ring_perm(s))
-    if len(axes) == 1:
-        return y
-    z = _ring_permute_nested(y, axes[:-1])
-    return jnp.where(jax.lax.axis_index(axes[-1]) == 0, z, y)
+    return jax.lax.ppermute(x, axes, ring_perm(worker_count(axes)))
 
 
 def _ring_chunk_spans(total_rows: int, chunk_rows: Optional[int]) -> tuple:
@@ -1718,7 +1692,7 @@ def make_vote_wire(impl: str, axes: Sequence[str], mesh=None, *,
                 f"a valid kernel grid, got {ring_chunk_rows!r}")
         ring_chunk_rows = r
     sizes = tuple(int(mesh.shape[a]) for a in axes) if mesh is not None \
-        else tuple(compat.axis_size(a) for a in axes)
+        else tuple(jax.lax.axis_size(a) for a in axes)
     # one build-time validation point: every per-size /n in the byte ledgers
     # (and the worker count itself) is safe downstream of this check
     if not axes or any(s < 1 for s in sizes):

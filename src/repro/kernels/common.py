@@ -43,10 +43,35 @@ def mix32(x):
     return x
 
 
-def encode2bit(x):
-    """ternary int8 {-1,0,1} -> 2-bit code uint8 {2,0,1} (the pack2bit wire
-    codebook); shared by the pack and fused compress+pack kernels."""
-    return jnp.where(x < 0, jnp.uint8(2), x.astype(jnp.uint8))
+def uniform24(bits):
+    """Top 24 bits of a uint32 hash as a float32 uniform in [0, 1).
+
+    The shift leaves a value below 2**24, which int32 and float32 both hold
+    exactly; the detour through int32 is there because Mosaic has no
+    uint32 -> float32 conversion."""
+    return (bits >> 8).astype(jnp.int32).astype(jnp.float32) * jnp.float32(1.0 / (1 << 24))
+
+
+def pack2bit_quads(t, quarter: int):
+    """(rows, 4*quarter) ternary {-1,0,1} -> (rows, quarter) uint8 in the
+    pack2bit wire codebook (0 -> 00, +1 -> 01, -1 -> 10): byte j packs the
+    codes of lane columns (j, j+q, j+2q, j+3q), the block-interleaved layout
+    of pack2bit/ref.py. Shared by the pack and fused compress+pack kernels.
+
+    Codes are built in int32 and narrowed once at the end: the TPU vector
+    unit has no 8-bit compares or shifts."""
+    t = t.astype(jnp.int32)
+    c = [jnp.where(q < 0, jnp.int32(2), q)
+         for q in (t[:, k * quarter:(k + 1) * quarter] for k in range(4))]
+    return (c[0] | (c[1] << 2) | (c[2] << 4) | (c[3] << 6)).astype(jnp.uint8)
+
+
+def decode2bit(codes, k: int):
+    """The k-th 2-bit field of int32 packed bytes -> vote in {-1, 0, 1}
+    (int32): code 1 -> +1, code 2 -> -1, 0 -> 0. Arithmetic, not a select,
+    so no boolean mask is relaid out across the worker axis."""
+    c = (codes >> (2 * k)) & 3
+    return (c & 1) - (c >> 1)
 
 
 def default_interpret() -> bool:
@@ -91,9 +116,10 @@ def block_rows_for(rows: int, want: int = DEFAULT_BLOCK_ROWS) -> int:
     return max(want, SUBLANE_PAD)
 
 
-def smem_scalar(x, dtype) -> jnp.ndarray:
-    """Scalars ride in SMEM as (1, 1) arrays."""
-    return jnp.asarray(x, dtype=dtype).reshape(1, 1)
+def smem_row(dtype, *xs) -> jnp.ndarray:
+    """Scalars ride in SMEM as one (1, k) row per dtype. Float scalars get a
+    float32 row of their own: Mosaic cannot bitcast an SMEM scalar."""
+    return jnp.stack([jnp.asarray(x, dtype) for x in xs]).reshape(1, len(xs))
 
 
 def hbm_elems(fn, *args, dtype=jnp.int8) -> int:

@@ -14,9 +14,10 @@ Sequential entropy coding needs the whole message in one kernel instance, so
 these kernels run a single-cell grid with the full canonical view as one
 block (VMEM-bounded by the engine's chunking for huge leaves; bucket slots
 are per-leaf messages and stay small). The emission helper leans on gather/
-scatter/prefix-sum jnp ops that interpret mode executes directly; a
-streaming-grid TPU lowering (per-block carry of bit offsets in SMEM) is the
-real-TPU half of ROADMAP's hardware validation pass.
+scatter/prefix-sum jnp ops that interpret mode executes directly and Mosaic
+does not lower (``cummax`` in the encoders, ``dynamic_slice`` in the
+decode-sums): these kernels run in interpret mode only. A streaming-grid TPU
+lowering (per-block carry of bit offsets in SMEM) is ROADMAP S2's open item.
 """
 
 from __future__ import annotations
@@ -28,16 +29,16 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.common import RNG_GOLDEN, mix32
+from repro.kernels.common import RNG_GOLDEN, mix32, uniform24
 from repro.kernels.golomb import ref as golomb_ref
 
 
-def _encode_kernel(scalars_ref, g_ref, out_ref, *, rows: int, lanes: int,
+def _encode_kernel(seeds_ref, budget_ref, g_ref, out_ref, *, rows: int, lanes: int,
                    b: int, out_rows: int):
-    # scalars: [seed, counter_base, budget_bits] packed as uint32 in SMEM.
-    seed = scalars_ref[0, 0]
-    counter_base = scalars_ref[0, 1]
-    budget = jax.lax.bitcast_convert_type(scalars_ref[0, 2], jnp.float32)
+    # SMEM: seeds_ref (1, 2) uint32 [seed, counter_base]; budget_ref (1, 1) f32
+    seed = seeds_ref[0, 0]
+    counter_base = seeds_ref[0, 1]
+    budget = budget_ref[0, 0]
 
     r = jax.lax.broadcasted_iota(jnp.uint32, (rows, lanes), 0)
     c = jax.lax.broadcasted_iota(jnp.uint32, (rows, lanes), 1)
@@ -45,7 +46,7 @@ def _encode_kernel(scalars_ref, g_ref, out_ref, *, rows: int, lanes: int,
 
     # counter-hash RNG (kernels/common.mix32 — mirrors repro.core.prng exactly)
     hbits = mix32((idx * RNG_GOLDEN) ^ mix32(seed + RNG_GOLDEN))
-    u = (hbits >> 8).astype(jnp.float32) * jnp.float32(1.0 / (1 << 24))
+    u = uniform24(hbits)
 
     g = g_ref[...].astype(jnp.float32)
     prob = jnp.clip(jnp.abs(g) * budget, 0.0, 1.0)
@@ -55,9 +56,10 @@ def _encode_kernel(scalars_ref, g_ref, out_ref, *, rows: int, lanes: int,
 
 
 @functools.partial(jax.jit, static_argnames=("b", "out_rows", "interpret"))
-def sparsign_golomb_2d(g2d: jnp.ndarray, scalars: jnp.ndarray, *,
-                       b: int, out_rows: int, interpret: bool):
-    """g2d: (rows, LANES) f32/bf16; scalars: (1,3) uint32 [seed, base, budget].
+def sparsign_golomb_2d(g2d: jnp.ndarray, seeds: jnp.ndarray, budget: jnp.ndarray,
+                       *, b: int, out_rows: int, interpret: bool):
+    """g2d: (rows, LANES) f32/bf16; seeds: (1,2) uint32 [seed, base]; budget:
+    (1,1) f32.
 
     Returns the (out_rows, ROW_BYTES) uint8 entropy-coded wire of
     sparsign(g2d) — out_rows is the static plan-time capacity
@@ -69,6 +71,7 @@ def sparsign_golomb_2d(g2d: jnp.ndarray, scalars: jnp.ndarray, *,
         grid=(1,),
         in_specs=[
             pl.BlockSpec(memory_space=pltpu.SMEM),
+            pl.BlockSpec(memory_space=pltpu.SMEM),
             pl.BlockSpec((rows, lanes), lambda i: (0, 0)),
         ],
         out_specs=pl.BlockSpec((out_rows, golomb_ref.ROW_BYTES),
@@ -76,7 +79,7 @@ def sparsign_golomb_2d(g2d: jnp.ndarray, scalars: jnp.ndarray, *,
         out_shape=jax.ShapeDtypeStruct((out_rows, golomb_ref.ROW_BYTES),
                                        jnp.uint8),
         interpret=interpret,
-    )(scalars, g2d)
+    )(seeds, budget, g2d)
 
 
 def _pack_kernel(t_ref, out_ref, *, b: int, out_rows: int):

@@ -46,12 +46,9 @@ def sparsign_golomb_op(
         interpret = common.default_interpret()
     n = int(g.size)
     view, _ = common.to_2d(g.reshape(-1))
-    budget_bits = jax.lax.bitcast_convert_type(
-        jnp.asarray(budget, jnp.float32), jnp.uint32)
-    scalars = jnp.stack(
-        [jnp.asarray(seed, jnp.uint32), jnp.asarray(counter_base, jnp.uint32),
-         budget_bits]).reshape(1, 3)
-    return sparsign_golomb_2d(view, scalars, b=golomb_ref.rice_b(p),
+    return sparsign_golomb_2d(view, common.smem_row(jnp.uint32, seed, counter_base),
+                              common.smem_row(jnp.float32, budget),
+                              b=golomb_ref.rice_b(p),
                               out_rows=golomb_ref.golomb_rows(n, p),
                               interpret=interpret)
 

@@ -19,36 +19,25 @@ from repro.kernels import common
 
 
 def _pack_kernel(t_ref, out_ref, *, quarter: int):
-    t = t_ref[...]
-    c0 = common.encode2bit(t[:, 0 * quarter:1 * quarter])
-    c1 = common.encode2bit(t[:, 1 * quarter:2 * quarter])
-    c2 = common.encode2bit(t[:, 2 * quarter:3 * quarter])
-    c3 = common.encode2bit(t[:, 3 * quarter:4 * quarter])
-    out_ref[...] = c0 | (c1 << 2) | (c2 << 4) | (c3 << 6)
+    out_ref[...] = common.pack2bit_quads(t_ref[...], quarter)
 
 
 def _unpack_kernel(p_ref, out_ref, *, quarter: int):
-    p = p_ref[...]
-
-    def dec(c):
-        return jnp.where(c == 1, jnp.int8(1), jnp.where(c == 2, jnp.int8(-1), jnp.int8(0)))
-
+    p = p_ref[...].astype(jnp.int32)
     for k in range(4):
-        out_ref[:, k * quarter:(k + 1) * quarter] = dec((p >> (2 * k)) & jnp.uint8(3))
+        out_ref[:, k * quarter:(k + 1) * quarter] = common.decode2bit(p, k).astype(jnp.int8)
 
 
-def _unpack_sum_kernel(p_ref, out_ref, *, quarter: int):
+def _unpack_sum_kernel(p_ref, out_ref, *, quarter: int, m: int):
     # p_ref block: (M, block_rows, quarter) uint8 — all workers' packed votes
     # for this row block. Decode and accumulate in VMEM; only the int32 vote
-    # sum (the psum-equivalent payload) is ever written back.
-    p = p_ref[...]
-
-    def dec(c):
-        return jnp.where(c == 1, jnp.int32(1), jnp.where(c == 2, jnp.int32(-1), jnp.int32(0)))
-
+    # sum (the psum-equivalent payload) is ever written back. Workers are
+    # unrolled so every value stays a 2-D (rows, lanes) tile.
     for k in range(4):
-        codes = (p >> (2 * k)) & jnp.uint8(3)
-        out_ref[:, k * quarter:(k + 1) * quarter] = jnp.sum(dec(codes), axis=0)
+        acc = common.decode2bit(p_ref[0].astype(jnp.int32), k)
+        for i in range(1, m):
+            acc = acc + common.decode2bit(p_ref[i].astype(jnp.int32), k)
+        out_ref[:, k * quarter:(k + 1) * quarter] = acc
 
 
 def _unpack_wsum_kernel(w_ref, p_ref, out_ref, *, quarter: int, m: int):
@@ -57,19 +46,14 @@ def _unpack_wsum_kernel(w_ref, p_ref, out_ref, *, quarter: int, m: int):
     # accumulator unrolls strictly in worker order so the float sum associates
     # exactly like the eager-loop oracle; a masked-out worker's zero payload
     # AND zero weight both force exact-zero contributions.
-    p = p_ref[...]
-
-    def dec(c):
-        return jnp.where(c == 1, jnp.float32(1.0),
-                         jnp.where(c == 2, jnp.float32(-1.0), jnp.float32(0.0)))
-
     for k in range(4):
-        codes = (p >> (2 * k)) & jnp.uint8(3)
+        votes = [common.decode2bit(p_ref[i].astype(jnp.int32), k).astype(jnp.float32)
+                 for i in range(m)]
         # zero seed (not acc = first term): a zero weight times a -1 vote is
         # -0.0, and the oracle's 0.0 + (-0.0) == +0.0 must be reproduced
-        acc = jnp.zeros_like(dec(codes[0]))
+        acc = jnp.zeros_like(votes[0])
         for i in range(m):
-            acc = acc + dec(codes[i]) * w_ref[0, i]
+            acc = acc + votes[i] * w_ref[0, i]
         out_ref[:, k * quarter:(k + 1) * quarter] = acc
 
 
@@ -98,7 +82,7 @@ def unpack2bit_sum_2d(p3d: jnp.ndarray, *, block_rows: int, interpret: bool) -> 
     m, rows, q = p3d.shape
     lanes = q * 4
     return pl.pallas_call(
-        functools.partial(_unpack_sum_kernel, quarter=q),
+        functools.partial(_unpack_sum_kernel, quarter=q, m=m),
         grid=(rows // block_rows,),
         in_specs=[pl.BlockSpec((m, block_rows, q), lambda i: (0, i, 0))],
         out_specs=pl.BlockSpec((block_rows, lanes), lambda i: (i, 0)),

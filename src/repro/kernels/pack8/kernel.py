@@ -27,15 +27,15 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.common import RNG_GOLDEN, mix32
+from repro.kernels.common import RNG_GOLDEN, mix32, uniform24
 from repro.kernels.pack8.ref import QSGD8_LEVELS
 
 
-def _qsgd8_kernel(scalars_ref, g_ref, out_ref, *, block_rows: int, lanes: int):
-    # scalars: [seed, counter_base, param_bits] packed as uint32 in SMEM.
-    seed = scalars_ref[0, 0]
-    counter_base = scalars_ref[0, 1]
-    param = jax.lax.bitcast_convert_type(scalars_ref[0, 2], jnp.float32)
+def _qsgd8_kernel(seeds_ref, param_ref, g_ref, out_ref, *, block_rows: int, lanes: int):
+    # SMEM: seeds_ref (1, 2) uint32 [seed, counter_base]; param_ref (1, 1) f32
+    seed = seeds_ref[0, 0]
+    counter_base = seeds_ref[0, 1]
+    param = param_ref[0, 0]
 
     r0 = pl.program_id(0) * block_rows
     rows = jax.lax.broadcasted_iota(jnp.uint32, (block_rows, lanes), 0)
@@ -44,7 +44,7 @@ def _qsgd8_kernel(scalars_ref, g_ref, out_ref, *, block_rows: int, lanes: int):
 
     # counter-hash RNG (kernels/common.mix32 — mirrors repro.core.prng exactly)
     bits = mix32((idx * RNG_GOLDEN) ^ mix32(seed + RNG_GOLDEN))
-    u = (bits >> 8).astype(jnp.float32) * jnp.float32(1.0 / (1 << 24))
+    u = uniform24(bits)
 
     g = g_ref[...].astype(jnp.float32)
     r = jnp.abs(g) / jnp.maximum(param, 1e-20)
@@ -88,9 +88,10 @@ def _unpack8_sum_kernel(scales_ref, p_ref, out_ref, dec_ref, *, m_chunk: int):
 
 
 @functools.partial(jax.jit, static_argnames=("block_rows", "interpret"))
-def qsgd8_pack8_2d(g2d: jnp.ndarray, scalars: jnp.ndarray, *,
+def qsgd8_pack8_2d(g2d: jnp.ndarray, seeds: jnp.ndarray, param: jnp.ndarray, *,
                    block_rows: int, interpret: bool) -> jnp.ndarray:
-    """g2d: (rows, LANES) f32/bf16; scalars: (1,3) uint32 [seed, base, param-bits].
+    """g2d: (rows, LANES) f32/bf16; seeds: (1,2) uint32 [seed, base]; param:
+    (1,1) f32.
 
     Returns the (rows, LANES) int8 signed-level wire payload of qsgd8(g2d)."""
     rows, lanes = g2d.shape
@@ -99,12 +100,13 @@ def qsgd8_pack8_2d(g2d: jnp.ndarray, scalars: jnp.ndarray, *,
         grid=(rows // block_rows,),
         in_specs=[
             pl.BlockSpec(memory_space=pltpu.SMEM),
+            pl.BlockSpec(memory_space=pltpu.SMEM),
             pl.BlockSpec((block_rows, lanes), lambda i: (i, 0)),
         ],
         out_specs=pl.BlockSpec((block_rows, lanes), lambda i: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((rows, lanes), jnp.int8),
         interpret=interpret,
-    )(scalars, g2d)
+    )(seeds, param, g2d)
 
 
 @functools.partial(jax.jit, static_argnames=("block_rows", "m_chunk", "interpret"))

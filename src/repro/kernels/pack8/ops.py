@@ -19,16 +19,6 @@ from repro.kernels import common
 from repro.kernels.pack8.kernel import qsgd8_pack8_2d, unpack8_sum_2d
 
 
-def _scalars(param, seed, counter_base) -> jnp.ndarray:
-    param_bits = jax.lax.bitcast_convert_type(
-        jnp.asarray(param, jnp.float32), jnp.uint32)
-    return jnp.stack([
-        jnp.asarray(seed, jnp.uint32),
-        jnp.asarray(counter_base, jnp.uint32),
-        param_bits,
-    ]).reshape(1, 3)
-
-
 @functools.partial(jax.jit, static_argnames=("interpret", "block_rows"))
 def qsgd8_pack8_op(
     g: jnp.ndarray,
@@ -46,7 +36,8 @@ def qsgd8_pack8_op(
         interpret = common.default_interpret()
     view, _ = common.to_2d(g.reshape(-1))
     br = block_rows or common.block_rows_for(view.shape[0])
-    return qsgd8_pack8_2d(view, _scalars(param, seed, counter_base),
+    return qsgd8_pack8_2d(view, common.smem_row(jnp.uint32, seed, counter_base),
+                          common.smem_row(jnp.float32, param),
                           block_rows=br, interpret=interpret)
 
 

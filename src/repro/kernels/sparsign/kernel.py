@@ -20,14 +20,14 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.common import RNG_GOLDEN, mix32
+from repro.kernels.common import RNG_GOLDEN, mix32, uniform24
 
 
-def _kernel(scalars_ref, g_ref, out_ref, *, block_rows: int, lanes: int):
-    # scalars: [seed, counter_base, budget_bits] packed as uint32 in SMEM.
-    seed = scalars_ref[0, 0]
-    counter_base = scalars_ref[0, 1]
-    budget = jax.lax.bitcast_convert_type(scalars_ref[0, 2], jnp.float32)
+def _kernel(seeds_ref, budget_ref, g_ref, out_ref, *, block_rows: int, lanes: int):
+    # SMEM: seeds_ref (1, 2) uint32 [seed, counter_base]; budget_ref (1, 1) f32
+    seed = seeds_ref[0, 0]
+    counter_base = seeds_ref[0, 1]
+    budget = budget_ref[0, 0]
 
     r0 = pl.program_id(0) * block_rows
     rows = jax.lax.broadcasted_iota(jnp.uint32, (block_rows, lanes), 0)
@@ -37,7 +37,7 @@ def _kernel(scalars_ref, g_ref, out_ref, *, block_rows: int, lanes: int):
     # counter-hash RNG (kernels/common.mix32 — mirrors repro.core.prng exactly)
     c = idx * RNG_GOLDEN
     bits = mix32(c ^ mix32(seed + RNG_GOLDEN))
-    u = (bits >> 8).astype(jnp.float32) * jnp.float32(1.0 / (1 << 24))
+    u = uniform24(bits)
 
     g = g_ref[...].astype(jnp.float32)
     p = jnp.clip(jnp.abs(g) * budget, 0.0, 1.0)
@@ -45,8 +45,10 @@ def _kernel(scalars_ref, g_ref, out_ref, *, block_rows: int, lanes: int):
 
 
 @functools.partial(jax.jit, static_argnames=("block_rows", "interpret"))
-def sparsign_2d(g2d: jnp.ndarray, scalars: jnp.ndarray, *, block_rows: int, interpret: bool):
-    """g2d: (rows, LANES) float32/bf16; scalars: (1,3) uint32 [seed, base, budget-bits]."""
+def sparsign_2d(g2d: jnp.ndarray, seeds: jnp.ndarray, budget: jnp.ndarray, *,
+                block_rows: int, interpret: bool):
+    """g2d: (rows, LANES) float32/bf16; seeds: (1,2) uint32 [seed, base];
+    budget: (1,1) f32."""
     rows, lanes = g2d.shape
     grid = (rows // block_rows,)
     return pl.pallas_call(
@@ -54,9 +56,10 @@ def sparsign_2d(g2d: jnp.ndarray, scalars: jnp.ndarray, *, block_rows: int, inte
         grid=grid,
         in_specs=[
             pl.BlockSpec(memory_space=pltpu.SMEM),
+            pl.BlockSpec(memory_space=pltpu.SMEM),
             pl.BlockSpec((block_rows, lanes), lambda i: (i, 0)),
         ],
         out_specs=pl.BlockSpec((block_rows, lanes), lambda i: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((rows, lanes), jnp.int8),
         interpret=interpret,
-    )(scalars, g2d)
+    )(seeds, budget, g2d)

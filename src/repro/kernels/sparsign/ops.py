@@ -27,9 +27,7 @@ def sparsign_op(
         interpret = common.default_interpret()
     view, n = common.to_2d(g.reshape(-1))
     br = block_rows or common.block_rows_for(view.shape[0])
-    budget_bits = jax.lax.bitcast_convert_type(jnp.asarray(budget, jnp.float32), jnp.uint32)
-    scalars = jnp.stack(
-        [jnp.asarray(seed, jnp.uint32), jnp.asarray(counter_base, jnp.uint32), budget_bits]
-    ).reshape(1, 3)
-    out2d = sparsign_2d(view, scalars, block_rows=br, interpret=interpret)
+    out2d = sparsign_2d(view, common.smem_row(jnp.uint32, seed, counter_base),
+                        common.smem_row(jnp.float32, budget),
+                        block_rows=br, interpret=interpret)
     return common.from_2d(out2d, n, g.shape)

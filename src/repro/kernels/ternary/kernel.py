@@ -26,20 +26,21 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.common import RNG_GOLDEN, encode2bit, mix32
+from repro.kernels.common import RNG_GOLDEN, mix32, pack2bit_quads, uniform24
 from repro.kernels.ternary.rules import RULES
 
-# scalars layout, (1, 6) uint32 in SMEM:
-#   [seed, fold(seed,1), fold(seed,2), counter_base, param_bits, n_valid]
-# the three seeds feed u(0)/u(1)/u(2); rules draw lazily, unused streams cost
-# nothing (the hash is only materialized when the rule calls u).
-N_SCALARS = 6
+# SMEM scalars: a (1, 5) uint32 row
+#   [seed, fold(seed,1), fold(seed,2), counter_base, n_valid]
+# and the rule's (1, 1) f32 param. The three seeds feed u(0)/u(1)/u(2); rules
+# draw lazily, unused streams cost nothing (the hash is only materialized
+# when the rule calls u).
+N_SCALARS = 5
 
 
-def _symbols(scalars_ref, g_ref, *, rule, block_rows: int, lanes: int):
+def _symbols(scalars_ref, param_ref, g_ref, *, rule, block_rows: int, lanes: int):
     counter_base = scalars_ref[0, 3]
-    param = jax.lax.bitcast_convert_type(scalars_ref[0, 4], jnp.float32)
-    n_valid = scalars_ref[0, 5]
+    param = param_ref[0, 0]
+    n_valid = scalars_ref[0, 4]
 
     r0 = pl.program_id(0) * block_rows
     rows = jax.lax.broadcasted_iota(jnp.uint32, (block_rows, lanes), 0)
@@ -51,34 +52,31 @@ def _symbols(scalars_ref, g_ref, *, rule, block_rows: int, lanes: int):
         # counter-hash RNG (kernels/common.mix32 — mirrors repro.core.prng);
         # salt picks the host-folded seed: 0 = unfolded, k = fold_seed(seed, k)
         bits = mix32((idx * RNG_GOLDEN) ^ mix32(scalars_ref[0, salt] + RNG_GOLDEN))
-        return (bits >> 8).astype(jnp.float32) * jnp.float32(1.0 / (1 << 24))
+        return uniform24(bits)
 
     g = g_ref[...].astype(jnp.float32)
     # mask the canonical-view padding: rules need not map 0 -> 0
     return jnp.where(pos < n_valid, rule(g, u, param), 0.0)
 
 
-def _compress_kernel(scalars_ref, g_ref, out_ref, *, rule, block_rows, lanes):
-    t = _symbols(scalars_ref, g_ref, rule=rule, block_rows=block_rows, lanes=lanes)
+def _compress_kernel(scalars_ref, param_ref, g_ref, out_ref, *, rule, block_rows, lanes):
+    t = _symbols(scalars_ref, param_ref, g_ref, rule=rule, block_rows=block_rows,
+                 lanes=lanes)
     out_ref[...] = t.astype(jnp.int8)
 
 
-def _pack2bit_kernel(scalars_ref, g_ref, out_ref, *, rule, block_rows, lanes):
-    t = _symbols(scalars_ref, g_ref, rule=rule, block_rows=block_rows,
-                 lanes=lanes).astype(jnp.int8)
+def _pack2bit_kernel(scalars_ref, param_ref, g_ref, out_ref, *, rule, block_rows, lanes):
+    t = _symbols(scalars_ref, param_ref, g_ref, rule=rule, block_rows=block_rows,
+                 lanes=lanes)
     # pack2bit's block-interleaved encoding, still in VMEM (see pack2bit/ref.py)
-    quarter = lanes // 4
-    c0 = encode2bit(t[:, 0 * quarter:1 * quarter])
-    c1 = encode2bit(t[:, 1 * quarter:2 * quarter])
-    c2 = encode2bit(t[:, 2 * quarter:3 * quarter])
-    c3 = encode2bit(t[:, 3 * quarter:4 * quarter])
-    out_ref[...] = c0 | (c1 << 2) | (c2 << 4) | (c3 << 6)
+    out_ref[...] = pack2bit_quads(t, lanes // 4)
 
 
 @functools.partial(jax.jit, static_argnames=("rule", "block_rows", "interpret"))
-def ternary_compress_2d(g2d: jnp.ndarray, scalars: jnp.ndarray, *,
-                        rule: str, block_rows: int, interpret: bool):
-    """g2d: (rows, LANES) f32/bf16; scalars: (1, N_SCALARS) uint32.
+def ternary_compress_2d(g2d: jnp.ndarray, scalars: jnp.ndarray, param: jnp.ndarray,
+                        *, rule: str, block_rows: int, interpret: bool):
+    """g2d: (rows, LANES) f32/bf16; scalars: (1, N_SCALARS) uint32; param:
+    (1, 1) f32.
     Returns the (rows, LANES) int8 ternary symbols of RULES[rule]."""
     rows, lanes = g2d.shape
     return pl.pallas_call(
@@ -87,17 +85,18 @@ def ternary_compress_2d(g2d: jnp.ndarray, scalars: jnp.ndarray, *,
         grid=(rows // block_rows,),
         in_specs=[
             pl.BlockSpec(memory_space=pltpu.SMEM),
+            pl.BlockSpec(memory_space=pltpu.SMEM),
             pl.BlockSpec((block_rows, lanes), lambda i: (i, 0)),
         ],
         out_specs=pl.BlockSpec((block_rows, lanes), lambda i: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((rows, lanes), jnp.int8),
         interpret=interpret,
-    )(scalars, g2d)
+    )(scalars, param, g2d)
 
 
 @functools.partial(jax.jit, static_argnames=("rule", "block_rows", "interpret"))
-def ternary_pack2bit_2d(g2d: jnp.ndarray, scalars: jnp.ndarray, *,
-                        rule: str, block_rows: int, interpret: bool):
+def ternary_pack2bit_2d(g2d: jnp.ndarray, scalars: jnp.ndarray, param: jnp.ndarray,
+                        *, rule: str, block_rows: int, interpret: bool):
     """Fused compress -> 2-bit packed wire: (rows, LANES) -> (rows, LANES//4)
     uint8, one HBM pass, no int8 ternary intermediate."""
     rows, lanes = g2d.shape
@@ -108,9 +107,10 @@ def ternary_pack2bit_2d(g2d: jnp.ndarray, scalars: jnp.ndarray, *,
         grid=(rows // block_rows,),
         in_specs=[
             pl.BlockSpec(memory_space=pltpu.SMEM),
+            pl.BlockSpec(memory_space=pltpu.SMEM),
             pl.BlockSpec((block_rows, lanes), lambda i: (i, 0)),
         ],
         out_specs=pl.BlockSpec((block_rows, q), lambda i: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((rows, q), jnp.uint8),
         interpret=interpret,
-    )(scalars, g2d)
+    )(scalars, param, g2d)

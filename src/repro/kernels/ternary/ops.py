@@ -20,21 +20,14 @@ from repro.kernels.ternary.kernel import (N_SCALARS, ternary_compress_2d,
                                           ternary_pack2bit_2d)
 
 
-def _scalars(param, seed, counter_base, n_valid) -> jnp.ndarray:
-    """(1, N_SCALARS) uint32 SMEM payload; seed folds happen host-side so the
-    kernel's u(salt) is a pure table read (see kernel.py layout)."""
-    param_bits = jax.lax.bitcast_convert_type(
-        jnp.asarray(param, jnp.float32), jnp.uint32)
-    s = jnp.stack([
-        jnp.asarray(seed, jnp.uint32),
-        prng.fold_seed(seed, 1),
-        prng.fold_seed(seed, 2),
-        jnp.asarray(counter_base, jnp.uint32),
-        param_bits,
-        jnp.asarray(n_valid, jnp.uint32),
-    ])
-    assert s.shape == (N_SCALARS,)
-    return s.reshape(1, N_SCALARS)
+def _scalars(param, seed, counter_base, n_valid):
+    """The (1, N_SCALARS) uint32 and (1, 1) f32 SMEM payloads; seed folds
+    happen host-side so the kernel's u(salt) is a pure table read (see
+    kernel.py layout)."""
+    ints = common.smem_row(jnp.uint32, seed, prng.fold_seed(seed, 1),
+                           prng.fold_seed(seed, 2), counter_base, n_valid)
+    assert ints.shape == (1, N_SCALARS)
+    return ints, common.smem_row(jnp.float32, param)
 
 
 @functools.partial(jax.jit, static_argnames=("rule", "interpret", "block_rows"))
@@ -53,7 +46,7 @@ def ternary_compress_op(
         interpret = common.default_interpret()
     view, n = common.to_2d(g.reshape(-1))
     br = block_rows or common.block_rows_for(view.shape[0])
-    out2d = ternary_compress_2d(view, _scalars(param, seed, counter_base, n),
+    out2d = ternary_compress_2d(view, *_scalars(param, seed, counter_base, n),
                                 rule=rule, block_rows=br, interpret=interpret)
     return common.from_2d(out2d, n, g.shape)
 
@@ -76,7 +69,7 @@ def ternary_pack2bit_op(
         interpret = common.default_interpret()
     view, n = common.to_2d(g.reshape(-1))
     br = block_rows or common.block_rows_for(view.shape[0])
-    return ternary_pack2bit_2d(view, _scalars(param, seed, counter_base, n),
+    return ternary_pack2bit_2d(view, *_scalars(param, seed, counter_base, n),
                                rule=rule, block_rows=br, interpret=interpret)
 
 

@@ -19,9 +19,10 @@ from jax.experimental.pallas import tpu as pltpu
 from repro.kernels import common
 
 
-def _kernel(scalars_ref, w_ref, v_ref, out_ref):
-    eta = jax.lax.bitcast_convert_type(scalars_ref[0, 0], jnp.float32)
-    quorum = scalars_ref[0, 1].astype(jnp.int32)
+def _kernel(eta_ref, quorum_ref, w_ref, v_ref, out_ref):
+    # SMEM: eta_ref (1, 1) f32, quorum_ref (1, 1) int32
+    eta = eta_ref[0, 0]
+    quorum = quorum_ref[0, 0]
     v = v_ref[...].astype(jnp.int32)
     step = jnp.where(jnp.abs(v) >= quorum, jnp.sign(v), 0).astype(jnp.float32)
     w = w_ref[...].astype(jnp.float32)
@@ -29,24 +30,25 @@ def _kernel(scalars_ref, w_ref, v_ref, out_ref):
 
 
 @functools.partial(jax.jit, static_argnames=("block_rows", "interpret"))
-def vote_update_2d(w2d, v2d, scalars, *, block_rows: int, interpret: bool):
+def vote_update_2d(w2d, v2d, eta, quorum, *, block_rows: int, interpret: bool):
     rows, lanes = w2d.shape
     spec_w = pl.BlockSpec((block_rows, lanes), lambda i: (i, 0))
     spec_v = pl.BlockSpec((block_rows, lanes), lambda i: (i, 0))
     return pl.pallas_call(
         _kernel,
         grid=(rows // block_rows,),
-        in_specs=[pl.BlockSpec(memory_space=pltpu.SMEM), spec_w, spec_v],
+        in_specs=[pl.BlockSpec(memory_space=pltpu.SMEM),
+                  pl.BlockSpec(memory_space=pltpu.SMEM), spec_w, spec_v],
         out_specs=spec_w,
         out_shape=jax.ShapeDtypeStruct((rows, lanes), w2d.dtype),
         interpret=interpret,
-    )(scalars, w2d, v2d)
+    )(eta, quorum, w2d, v2d)
 
 
 def _wkernel(scalars_ref, w_ref, v_ref, t_ref, out_ref):
-    # scalars: [eta bits, q_frac bits] — both f32 payloads in SMEM uint32
-    eta = jax.lax.bitcast_convert_type(scalars_ref[0, 0], jnp.float32)
-    q_frac = jax.lax.bitcast_convert_type(scalars_ref[0, 1], jnp.float32)
+    # SMEM: scalars_ref (1, 2) f32 [eta, q_frac]
+    eta = scalars_ref[0, 0]
+    q_frac = scalars_ref[0, 1]
     v = v_ref[...].astype(jnp.float32)
     thr = q_frac * t_ref[...].astype(jnp.float32)
     step = jnp.where(jnp.abs(v) >= thr, jnp.sign(v), jnp.float32(0.0))

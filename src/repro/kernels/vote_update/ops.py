@@ -20,9 +20,9 @@ def vote_update_op(w: jnp.ndarray, votes: jnp.ndarray, eta, *, quorum: int = 1,
     w2, n = common.to_2d(w.reshape(-1))
     v2, _ = common.to_2d(votes.reshape(-1))
     br = common.block_rows_for(w2.shape[0])
-    eta_bits = jax.lax.bitcast_convert_type(jnp.asarray(eta, jnp.float32), jnp.uint32)
-    scalars = jnp.stack([eta_bits, jnp.asarray(quorum, jnp.uint32)]).reshape(1, 2)
-    out2 = vote_update_2d(w2, v2, scalars, block_rows=br, interpret=interpret)
+    out2 = vote_update_2d(w2, v2, common.smem_row(jnp.float32, eta),
+                          common.smem_row(jnp.int32, quorum),
+                          block_rows=br, interpret=interpret)
     return common.from_2d(out2, n, w.shape)
 
 
@@ -43,9 +43,7 @@ def weighted_vote_update_op(w: jnp.ndarray, wvotes: jnp.ndarray, wtot,
     t = jnp.broadcast_to(jnp.asarray(wtot, jnp.float32), wvotes.shape)
     t2, _ = common.to_2d(t.reshape(-1))
     br = common.block_rows_for(w2.shape[0])
-    eta_bits = jax.lax.bitcast_convert_type(jnp.asarray(eta, jnp.float32), jnp.uint32)
-    qf_bits = jax.lax.bitcast_convert_type(jnp.asarray(q_frac, jnp.float32), jnp.uint32)
-    scalars = jnp.stack([eta_bits, qf_bits]).reshape(1, 2)
+    scalars = common.smem_row(jnp.float32, eta, q_frac)
     out2 = weighted_vote_update_2d(w2, v2, t2, scalars, block_rows=br,
                                    interpret=interpret)
     return common.from_2d(out2, n, w.shape)

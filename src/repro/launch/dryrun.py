@@ -18,7 +18,6 @@ from repro.configs.registry import ARCH_IDS, get_config, trainer_mode
 from repro.configs.shapes import SHAPES, applicable
 from repro.core.algorithm import CompressionConfig
 from repro.core.budgets import BudgetConfig
-from repro.dist import compat
 from repro.dist.sharding import tp_param_shardings
 from repro.launch import hlo_stats
 from repro.launch.mesh import make_production_mesh, worker_axes_of
@@ -194,7 +193,7 @@ def run_cell(arch: str, shape_name: str, *, multi_pod: bool, args) -> dict:
             step = build_step(arch, shape_name, mesh, mode=mode, comp=comp,
                               vote_impl=args.vote_impl, cfg_override=cfg,
                               pure_dp=pure_dp)
-            with compat.set_mesh(mesh):
+            with jax.sharding.set_mesh(mesh):
                 specs = input_specs_with_cfg(cfg, shape_name, mesh, mode=mode, comp=comp,
                                              tau=args.tau, pure_dp=pure_dp)
                 lowered = step.lower(*specs)
@@ -203,8 +202,6 @@ def run_cell(arch: str, shape_name: str, *, multi_pod: bool, args) -> dict:
                 t_compile = time.time() - t0 - t_lower
             entry = {"lower_s": round(t_lower, 1), "compile_s": round(t_compile, 1)}
             ca = compiled.cost_analysis() or {}
-            if isinstance(ca, (list, tuple)):  # jax 0.4.x: list of per-device dicts
-                ca = ca[0] if ca else {}
             entry["flops"] = float(ca.get("flops", 0.0))
             entry["bytes_accessed"] = float(ca.get("bytes accessed", 0.0))
             text = compiled.as_text()

@@ -1,9 +1,9 @@
 """Training launcher: ``python -m repro.launch.train --arch <id> [...]``.
 
-On this CPU container it drives reduced (smoke) configs end-to-end — the same
-code path a TPU deployment uses with the full configs and the production mesh
-(the mesh geometry and trainer mode come from the registry; nothing else
-changes). Checkpoints/resume/failure-injection are live here.
+The default smoke config drives the whole path on a CPU; ``--full`` takes the
+registry config at its published widths (what ``chip_smoke.py`` runs on a
+TPU). The mesh geometry and trainer mode come from the registry; nothing else
+changes. Checkpoints/resume/failure-injection are live here.
 """
 
 from __future__ import annotations
@@ -14,12 +14,15 @@ import json
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.sharding import NamedSharding, PartitionSpec as P
 
 from repro.configs.registry import ARCH_IDS, get_config, trainer_mode
 from repro.core.algorithm import CompressionConfig
 from repro.core.budgets import BudgetConfig
+from repro.core.engine import BACKENDS
 from repro.data.synthetic import LMStreamConfig, lm_batch
-from repro.dist import collectives, compat
+from repro.dist import collectives
+from repro.launch.compile_cache import enable_compile_cache
 from repro.launch.mesh import make_host_mesh, make_production_mesh, worker_axes_of
 from repro.models.model import Model
 from repro.train import loop as loop_lib
@@ -65,19 +68,20 @@ def build_everything(args):
         step = build_train_step(model, TrainStepConfig(
             compression=comp, lr=lr, local_lr=args.local_lr, worker_axes=wa,
             vote_impl=args.vote_impl, quorum=args.quorum,
-            bucketed=args.bucketed,
+            backend=args.backend, bucketed=args.bucketed,
             ring_chunk_rows=ring_rows, participation=part), mesh)
-        params = model.init(jax.random.PRNGKey(args.seed))
+        params = jax.device_put(model.init(jax.random.PRNGKey(args.seed)),
+                                NamedSharding(mesh, P()))
     else:
         step = build_streamed_train_step(model, StreamedStepConfig(
             compression=comp, lr=lr, worker_axes=wa,
             vote_impl=args.vote_impl, quorum=args.quorum,
-            bucketed=args.bucketed,
+            backend=args.backend, bucketed=args.bucketed,
             ring_chunk_rows=ring_rows, participation=part), mesh)
         params = model.init(jax.random.PRNGKey(args.seed))
         params = jax.tree_util.tree_map(jax.device_put, params,
                                         fsdp_param_shardings(model, mesh))
-    state = init_state(params, server=comp.server, seed=args.seed)
+    state = init_state(params, server=comp.server, seed=args.seed, mesh=mesh)
     return cfg, model, mesh, step, state, comp
 
 
@@ -102,7 +106,7 @@ def batch_fn_for(cfg, args):
     return fn
 
 
-def main(argv=None):
+def build_parser() -> argparse.ArgumentParser:
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", required=True, choices=ARCH_IDS)
     ap.add_argument("--smoke", action="store_true", default=True)
@@ -161,17 +165,24 @@ def main(argv=None):
     ap.add_argument("--ring-chunk-rows", type=int, default=None,
                     help="payload rows per ring chunk (multiple of 32; "
                          f"default {collectives.DEFAULT_RING_CHUNK_ROWS})")
+    ap.add_argument("--backend", default=None, choices=BACKENDS,
+                    help="compression kernel backend (default: pallas on a "
+                         "TPU, jnp elsewhere; $REPRO_KERNEL_BACKEND)")
     ap.add_argument("--seed", type=int, default=0)
     ap.add_argument("--ckpt-dir", default=None)
     ap.add_argument("--ckpt-every", type=int, default=50)
     ap.add_argument("--fail-at", type=int, default=None)
     ap.add_argument("--history-out", default=None)
-    args = ap.parse_args(argv)
+    return ap
 
+
+def main(argv=None):
+    args = build_parser().parse_args(argv)
+    enable_compile_cache()
     cfg, model, mesh, step, state, comp = build_everything(args)
     lcfg = loop_lib.LoopConfig(total_steps=args.steps, ckpt_dir=args.ckpt_dir,
                                ckpt_every=args.ckpt_every, fail_at_step=args.fail_at)
-    with compat.set_mesh(mesh):
+    with jax.sharding.set_mesh(mesh):
         state, history = loop_lib.run(step, state, batch_fn_for(cfg, args), lcfg)
     if args.history_out:
         with open(args.history_out, "w") as f:

@@ -61,29 +61,20 @@ def hint(x: jnp.ndarray, *logical: Optional[str]) -> jnp.ndarray:
         seen[key] = i
     if all(s is None for s in spec):
         return x
-    from repro.dist import compat
-    if compat.HAS_ABSTRACT_MESH_CTX:
-        # Inside shard_map / set_mesh, the ambient mesh is an AbstractMesh
-        # (with Manual axis types under shard_map); a NamedSharding built from
-        # the concrete mesh MISMATCHES it and the constraint is dropped. A bare
-        # PartitionSpec resolves against the ambient mesh, which is what we want.
-        am = jax.sharding.get_abstract_mesh()
-        if am is not None and not getattr(am, "empty", False) and am.axis_names:
-            return jax.lax.with_sharding_constraint(x, P(*spec))
-        mesh = _MESH.get()
-        if mesh is not None:
-            return jax.lax.with_sharding_constraint(x, NamedSharding(mesh, P(*spec)))
-        return x
-    # jax 0.4.x: no ambient abstract mesh. Constraints may not name a manual
-    # axis, and the compat shard_map takes EVERY mesh axis manual — null those
-    # entries (the shard_map specs already fix their placement).
-    from repro.dist.sharding import _entry_names
-    manual = compat.manual_axis_names()
-    if manual:
+    am = jax.sharding.get_abstract_mesh()
+    if not am.empty:
+        # Inside shard_map / set_mesh the ambient mesh is an AbstractMesh; a
+        # NamedSharding built from the concrete mesh MISMATCHES it, so a bare
+        # PartitionSpec resolves against it. A constraint may only name Auto
+        # axes: entries naming a Manual axis (the shard_map's worker axes)
+        # are nulled, since the shard_map specs already fix their placement.
+        from repro.dist.sharding import _entry_names
+        manual = set(am.manual_axes)
         spec = [None if s is not None and set(_entry_names(s)) & manual else s
                 for s in spec]
         if all(s is None for s in spec):
             return x
+        return jax.lax.with_sharding_constraint(x, P(*spec))
     mesh = _MESH.get()
     if mesh is not None:
         return jax.lax.with_sharding_constraint(x, NamedSharding(mesh, P(*spec)))

@@ -7,6 +7,7 @@ from typing import Any, Optional
 
 import jax
 import jax.numpy as jnp
+from jax.sharding import NamedSharding, PartitionSpec as P
 
 from repro.core.engine import needs_server_ef
 
@@ -20,16 +21,19 @@ class TrainState:
     seed: jnp.ndarray         # uint32 base seed
 
 
-def init_state(params, *, server: str, seed: int) -> TrainState:
+def init_state(params, *, server: str, seed: int, mesh=None) -> TrainState:
+    """Fresh state around ``params``. The EF residual takes the params'
+    placement; with ``mesh`` the two counters are committed replicated on it.
+    Placed params plus ``mesh`` give the state the placement the trainers'
+    steps return, so step 0 and every later step share one executable."""
     ef = None
     if needs_server_ef(server):
-        ef = jax.tree_util.tree_map(lambda p: jnp.zeros(p.shape, jnp.float32), params)
-    return TrainState(
-        params=params,
-        ef_residual=ef,
-        step=jnp.int32(0),
-        seed=jnp.uint32(seed),
-    )
+        ef = jax.tree_util.tree_map(
+            lambda p: jnp.zeros_like(p, dtype=jnp.float32), params)
+    step, seed = jnp.int32(0), jnp.uint32(seed)
+    if mesh is not None:
+        step, seed = jax.device_put((step, seed), NamedSharding(mesh, P()))
+    return TrainState(params=params, ef_residual=ef, step=step, seed=seed)
 
 
 @dataclasses.dataclass(frozen=True)

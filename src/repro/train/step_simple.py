@@ -41,7 +41,7 @@ from jax.sharding import PartitionSpec as P
 
 from repro.core import engine, prng
 from repro.core.algorithm import CompressionConfig
-from repro.dist import bucketing, collectives, compat
+from repro.dist import bucketing, collectives
 from repro.dist.sharding import ACT_RULES_TRAIN
 from repro.models.common import axis_rules
 from repro.train import sampling
@@ -452,12 +452,12 @@ def build_train_step(model, step_cfg: TrainStepConfig, mesh) -> Callable:
         spec[batch_axis] = axes if len(axes) > 1 else axes[0]
         return P(*spec[:batch_axis + 1])
 
-    wrapped = compat.shard_map(
+    wrapped = jax.shard_map(
         body,
         mesh=mesh,
         in_specs=(state_spec, batch_spec()),
         out_specs=(state_spec, state_spec),
-        axis_names=set(axes),
+        axis_names=engine.manual_axes(backend, mesh, axes),
         check_vma=False,
     )
     if step_cfg.donate:

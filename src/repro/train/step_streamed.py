@@ -35,7 +35,7 @@ from jax.sharding import PartitionSpec as P
 
 from repro.core import engine, prng
 from repro.core.algorithm import CompressionConfig
-from repro.dist import bucketing, collectives, compat
+from repro.dist import bucketing, collectives
 from repro.dist.sharding import ACT_RULES_TRAIN
 from repro.models.common import axis_rules, rms_norm
 from repro.train import sampling
@@ -774,11 +774,11 @@ def build_streamed_train_step(model, step_cfg: StreamedStepConfig, mesh) -> Call
         step=P(), seed=P())
     batch_spec = P(axes if len(axes) > 1 else axes[0])
 
-    wrapped = compat.shard_map(
+    wrapped = jax.shard_map(
         body, mesh=mesh,
         in_specs=(state_specs, batch_spec),
         out_specs=(state_specs, P()),
-        axis_names=set(axes) | {fsdp_ax},
+        axis_names=engine.manual_axes(backend, mesh, set(axes) | {fsdp_ax}),
         check_vma=False,
     )
     if step_cfg.donate:

@@ -28,14 +28,16 @@ MDEV_DIR = pathlib.Path(__file__).parent / "mdev"
 SRC_DIR = str(pathlib.Path(__file__).parents[1] / "src")
 
 
-def run_mdev(script: str, timeout: int = 1200) -> str:
+def run_mdev(script: str, timeout: int = 1200, args=()) -> str:
     """Run a tests/mdev/ check in a subprocess (own XLA_FLAGS / device count)
-    and return its stdout; asserts a zero exit."""
+    and return its stdout; asserts a zero exit. The child is held to the CPU:
+    it must not reach for an accelerator another process may hold."""
     proc = subprocess.run(
-        [sys.executable, str(MDEV_DIR / script)],
+        [sys.executable, str(MDEV_DIR / script), *args],
         capture_output=True, text=True, timeout=timeout,
         env={"PYTHONPATH": SRC_DIR,
              "PATH": os.environ.get("PATH", "/usr/bin:/bin"),
+             "JAX_PLATFORMS": "cpu",
              "HOME": os.environ.get("HOME", "/root")},
     )
     assert proc.returncode == 0, \

@@ -14,7 +14,8 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import PartitionSpec as P
 
-from repro.dist import collectives, compat
+from repro.dist import collectives
+from repro.launch.mesh import make_mesh
 
 SHAPE = (3, 257)  # deliberately unaligned with the pack2bit canonical view
 
@@ -28,7 +29,7 @@ def main():
     assert jax.device_count() == 8, jax.device_count()
 
     # ---- flat mesh: psum vs packed all-gather vs oracle --------------------
-    mesh = compat.make_mesh((4, 2), ("data", "model"))
+    mesh = make_mesh((4, 2), ("data", "model"))
     votes = worker_votes(4, seed=1)
     stacked = jnp.asarray(votes.reshape(4 * SHAPE[0], SHAPE[1]))
 
@@ -41,7 +42,7 @@ def main():
         gi = jax.lax.all_gather(i, ("data",), axis=0)
         return a.astype(jnp.int32), b.astype(jnp.int32), gi
 
-    step = jax.jit(compat.shard_map(
+    step = jax.jit(jax.shard_map(
         body, mesh=mesh,
         in_specs=P("data"),
         out_specs=(P(), P(), P()),
@@ -54,7 +55,7 @@ def main():
     print("OK vote_psum == vote_allgather_packed == oracle (4 workers)")
 
     # ---- hierarchical mesh: two-level psum vs flat -------------------------
-    mesh3 = compat.make_mesh((2, 2, 2), ("pod", "data", "model"))
+    mesh3 = make_mesh((2, 2, 2), ("pod", "data", "model"))
     votes8 = worker_votes(4, seed=2)  # 4 workers = pod x data
     stacked8 = jnp.asarray(votes8.reshape(4 * SHAPE[0], SHAPE[1]))
 
@@ -65,14 +66,14 @@ def main():
         flat = collectives.vote_psum(v, axes, n)
         hier = collectives.vote_psum_hier(
             v, "data", "pod",
-            collectives.axis_size("data"), collectives.axis_size("pod"))
+            jax.lax.axis_size("data"), jax.lax.axis_size("pod"))
         packed = collectives.vote_allgather_packed(v, axes, n)
         idx = collectives.worker_index(axes)
         gi = jax.lax.all_gather(idx, axes, axis=0)
         return (flat.astype(jnp.int32), hier.astype(jnp.int32),
                 packed.astype(jnp.int32), gi)
 
-    step3 = jax.jit(compat.shard_map(
+    step3 = jax.jit(jax.shard_map(
         body3, mesh=mesh3,
         in_specs=P(("pod", "data")),
         out_specs=(P(), P(), P(), P()),

@@ -27,7 +27,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from repro.dist import compat
+from repro.launch.mesh import make_mesh
 from repro.dist.collectives import ParticipationSpec
 from repro.configs.base import LayerSpec, ModelConfig
 from repro.configs.registry import get_config
@@ -43,7 +43,7 @@ CKPT = "/tmp/repro_ft_ckpt"
 
 
 def setup(mesh_shape=(4, 2)):
-    mesh = compat.make_mesh(mesh_shape, ("data", "model"))
+    mesh = make_mesh(mesh_shape, ("data", "model"))
     cfg = get_config("qwen1.5-4b", smoke=True)
     model = Model(cfg)
     comp = CompressionConfig(compressor="sparsign", budget=BudgetConfig(value=2.0),
@@ -80,14 +80,14 @@ def tiny_batch(vocab, rows, step_i):
 
 def run_tiny(mesh_shape, comp, n_steps, batch_of, **cfg_kw):
     """n_steps of the tiny model on a fresh mesh; returns (params, metrics list)."""
-    mesh = compat.make_mesh(mesh_shape, ("data", "model"))
+    mesh = make_mesh(mesh_shape, ("data", "model"))
     model = tiny_model()
     step = build_train_step(model, TrainStepConfig(
         compression=comp, lr=LrSchedule(base=0.05), worker_axes=("data",),
         donate=False, **cfg_kw), mesh)
     state = init_state(model.init(jax.random.PRNGKey(0)), server=comp.server, seed=7)
     hist = []
-    with compat.set_mesh(mesh):
+    with jax.sharding.set_mesh(mesh):
         for i in range(n_steps):
             state, metrics = step(state, batch_of(i))
             hist.append({k: float(v) for k, v in metrics.items()
@@ -201,7 +201,7 @@ def main():
     shutil.rmtree(CKPT, ignore_errors=True)
     # --- run A: uninterrupted ---
     mesh, step, state, batch_fn = setup()
-    with compat.set_mesh(mesh):
+    with jax.sharding.set_mesh(mesh):
         ref_state, _ = loop_lib.run(step, state, batch_fn,
                                     loop_lib.LoopConfig(total_steps=8, log_every=100))
     # --- run B: checkpoint every 2, die at 5, restart ---
@@ -210,7 +210,7 @@ def main():
                                fail_at_step=5, log_every=100)
     died = False
     try:
-        with compat.set_mesh(mesh):
+        with jax.sharding.set_mesh(mesh):
             loop_lib.run(step, state, batch_fn, cfgB)
     except RuntimeError as e:
         died = True
@@ -219,7 +219,7 @@ def main():
     # restart (fresh everything, as after a pod loss)
     mesh, step, state, batch_fn = setup()
     cfgB2 = loop_lib.LoopConfig(total_steps=8, ckpt_dir=CKPT, ckpt_every=2, log_every=100)
-    with compat.set_mesh(mesh):
+    with jax.sharding.set_mesh(mesh):
         state_b, _ = loop_lib.run(step, state, batch_fn, cfgB2)
     for pa, pb in zip(jax.tree_util.tree_leaves(ref_state.params),
                       jax.tree_util.tree_leaves(state_b.params)):
@@ -228,7 +228,7 @@ def main():
 
     # --- elastic: restore the checkpoint on a (2, 4) mesh and keep training ---
     mesh2, step2, state2, batch_fn2 = setup(mesh_shape=(2, 4))
-    with compat.set_mesh(mesh2):
+    with jax.sharding.set_mesh(mesh2):
         state2b, hist = loop_lib.run(step2, state2, batch_fn2,
                                      loop_lib.LoopConfig(total_steps=10, ckpt_dir=CKPT,
                                                          ckpt_every=100, log_every=100))

@@ -12,7 +12,7 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import PartitionSpec as P
 
-from repro.dist import compat
+from repro.launch.mesh import make_mesh
 from repro.configs.registry import get_config
 from repro.core import prng
 from repro.core.algorithm import CompressionConfig
@@ -59,7 +59,7 @@ def oracle_step(model, params, batch, comp, lr, n_workers, seed):
 
 def main():
     assert jax.device_count() == 8, jax.device_count()
-    mesh = compat.make_mesh((4, 2), ("data", "model"))
+    mesh = make_mesh((4, 2), ("data", "model"))
     cfg = get_config("qwen1.5-4b", smoke=True)
     model = Model(cfg)
     params = model.init(jax.random.PRNGKey(0))
@@ -71,7 +71,7 @@ def main():
     state = init_state(params, server=comp.server, seed=1234)
     batch = make_batch(cfg, b=8, s=16)
 
-    with compat.set_mesh(mesh):
+    with jax.sharding.set_mesh(mesh):
         new_state, metrics = step(state, batch)
     got = jax.tree_util.tree_map(np.asarray, new_state.params)
     want = jax.tree_util.tree_map(np.asarray, oracle_step(model, params, batch, comp, 0.01, 4, 1234))
@@ -94,7 +94,7 @@ def main():
     scfg_i = TrainStepConfig(compression=comp, lr=lr_sched, worker_axes=("data",),
                              donate=False, backend="interpret")
     step_i = build_train_step(model, scfg_i, mesh)
-    with compat.set_mesh(mesh):
+    with jax.sharding.set_mesh(mesh):
         st_i, _ = step_i(state, batch)
     flat_i = jax.tree_util.tree_flatten(
         jax.tree_util.tree_map(np.asarray, st_i.params))[0]
@@ -108,7 +108,7 @@ def main():
     scfg2 = TrainStepConfig(compression=comp2, lr=lr_sched, worker_axes=("data",), donate=False)
     step2 = build_train_step(model, scfg2, mesh)
     state2 = init_state(params, server=comp2.server, seed=99)
-    with compat.set_mesh(mesh):
+    with jax.sharding.set_mesh(mesh):
         s2, m2 = step2(state2, batch)
         s2, m2 = step2(s2, batch)
     efn = sum(float(jnp.sum(x**2)) for x in jax.tree_util.tree_leaves(s2.ef_residual))
@@ -122,7 +122,7 @@ def main():
     step3 = build_train_step(model, scfg3, mesh)
     state3 = init_state(params, server=comp3.server, seed=7)
     tb = jax.tree_util.tree_map(lambda x: jnp.stack([x, x]), batch)  # tau leading axis
-    with compat.set_mesh(mesh):
+    with jax.sharding.set_mesh(mesh):
         s3, m3 = step3(state3, tb)
     assert np.isfinite(float(m3["loss"]))
     print("OK local-update (tau=2) EF-SPARSIGNSGD step, loss:", float(m3["loss"]))

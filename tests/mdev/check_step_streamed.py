@@ -15,7 +15,7 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import NamedSharding, PartitionSpec as P
 
-from repro.dist import compat
+from repro.launch.mesh import make_mesh
 from repro.configs.registry import get_config
 from repro.core.algorithm import CompressionConfig
 from repro.core.budgets import BudgetConfig
@@ -33,7 +33,7 @@ def make_batch(cfg, b, s, key=0):
     }
 
 def main():
-    mesh = compat.make_mesh((4, 2), ("data", "model"))
+    mesh = make_mesh((4, 2), ("data", "model"))
     cfg = get_config("qwen2-moe-a2.7b", smoke=True)
     model = Model(cfg)
     params = model.init(jax.random.PRNGKey(0))
@@ -46,7 +46,7 @@ def main():
     s_simple = init_state(params, server=comp.server, seed=42)
     step_simple = build_train_step(model, TrainStepConfig(
         compression=comp, lr=lr, worker_axes=("data",), donate=False), mesh)
-    with compat.set_mesh(mesh):
+    with jax.sharding.set_mesh(mesh):
         out_simple, m_simple = step_simple(s_simple, batch)
     ref = jax.tree_util.tree_map(np.asarray, out_simple.params)
 
@@ -56,7 +56,7 @@ def main():
     s_str = init_state(params_sh, server=comp.server, seed=42)
     step_str = build_streamed_train_step(model, StreamedStepConfig(
         compression=comp, lr=lr, worker_axes=("data",), fsdp_axis="data", donate=False), mesh)
-    with compat.set_mesh(mesh):
+    with jax.sharding.set_mesh(mesh):
         out_str, m_str = step_str(s_str, batch)
     got = jax.tree_util.tree_map(np.asarray, out_str.params)
 
@@ -90,7 +90,7 @@ def main():
         lambda p, sh: jax.device_put(jnp.zeros(p.shape, jnp.float32), sh), params_sh, ef_shardings)
     step_ef = build_streamed_train_step(model, StreamedStepConfig(
         compression=comp_ef, lr=lr, worker_axes=("data",), donate=False), mesh)
-    with compat.set_mesh(mesh):
+    with jax.sharding.set_mesh(mesh):
         o1, m1 = step_ef(s_ef, batch)
         o2, m2 = step_ef(o1, batch)
     assert np.isfinite(float(m2["loss"]))
@@ -112,7 +112,7 @@ def main():
                     fsdp_axis="data", vote_impl=vote_impl, donate=False,
                     backend=backend, bucketed=bucketed), mesh)
                 st = init_state(params_sh, server=comp_w.server, seed=42)
-                with compat.set_mesh(mesh):
+                with jax.sharding.set_mesh(mesh):
                     out, m = step(st, batch)
                 got = jax.tree_util.tree_leaves(
                     jax.tree_util.tree_map(np.asarray, out.params))

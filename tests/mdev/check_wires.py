@@ -49,13 +49,14 @@ multi-leaf bucket buffers, exercising genuinely multi-chunk rings at
 RING_CHUNK_ROWS=32.
 """
 import os
+import sys
 os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 
-from repro.dist import compat
+from repro.launch.mesh import make_mesh
 from repro.configs.registry import get_config
 from repro.core.algorithm import CompressionConfig
 from repro.core.budgets import BudgetConfig
@@ -69,6 +70,7 @@ from repro.train.step_streamed import (StreamedStepConfig,
 AXES = ("pod", "data")
 WIRES = ("psum", "hier", "allgather_packed")
 BACKENDS = ("jnp", "interpret")
+PARTS = ("simple", "streamed")
 RING_CHUNK_ROWS = 32   # smallest legal chunk -> forces multi-chunk rings on
                        # the bucketed plans (per-leaf smoke leaves fit in one)
 
@@ -102,7 +104,7 @@ def check_mode(mode, mesh, model, params, batch, comp, lr, wires=WIRES):
                                           donate=False, backend=backend)
                 step = build_streamed_train_step(model, scfg, mesh)
                 state = init_state(params, server=comp.server, seed=42)
-            with compat.set_mesh(mesh):
+            with jax.sharding.set_mesh(mesh):
                 out, metrics = step(state, batch)
             got = flat_np(out.params)
             label = f"{mode}/{wire}/{backend}"
@@ -145,12 +147,12 @@ def check_ring(mode, mesh, model, params, batch, comp, lr, *,
             step = _build(mode, mesh, model, comp, lr, backend,
                           ring=ring, bucketed=bucketed)
             state = init_state(params, server=comp.server, seed=42)
-            with compat.set_mesh(mesh):
+            with jax.sharding.set_mesh(mesh):
                 out, metrics = step(state, batch)
             if ring is not None:
                 # run-twice determinism of the ring stream
                 state2 = init_state(params, server=comp.server, seed=42)
-                with compat.set_mesh(mesh):
+                with jax.sharding.set_mesh(mesh):
                     out2, _ = step(state2, batch)
                 nd = sum(int((a != b).sum()) for a, b in
                          zip(flat_np(out.params), flat_np(out2.params)))
@@ -173,43 +175,59 @@ def check_ring(mode, mesh, model, params, batch, comp, lr, *,
               f"(gather_hbm {hbm[0]:.0f} -> {hbm[1]:.0f} B)")
 
 
-def check_ring_permute_fallback(mesh):
-    """ring_permute over the 2-axis worker group: the tuple-axis ppermute and
-    the old-jax nested fallback (compat.HAS_TUPLE_PPERMUTE=False) must both
-    rotate the flat worker ring by one."""
+def check_ring_permute(mesh):
+    """ring_permute over the 2-axis worker group rotates the flat
+    (row-major) worker ring by one."""
     from jax.sharding import PartitionSpec as P
-    from repro.dist import collectives, compat as _compat
+    from repro.dist import collectives
 
     x = np.arange(4 * 8, dtype=np.int32).reshape(4, 8)
     expect = np.roll(x, 1, axis=0)   # worker w receives worker w-1's slice
 
-    def run():
-        def f(v):
-            return collectives.ring_permute(v, AXES)
-        g = compat.shard_map(f, mesh=mesh, in_specs=P(AXES),
-                             out_specs=P(AXES),
-                             axis_names=set(AXES), check_vma=False)
-        with compat.set_mesh(mesh):
-            return np.asarray(g(jnp.asarray(x)))
-
-    np.testing.assert_array_equal(run(), expect)
-    orig = _compat.HAS_TUPLE_PPERMUTE
-    _compat.HAS_TUPLE_PPERMUTE = False
-    try:
-        np.testing.assert_array_equal(run(), expect)
-    finally:
-        _compat.HAS_TUPLE_PPERMUTE = orig
-    print("  OK ring_permute tuple-axis == nested single-axis fallback")
+    g = jax.jit(jax.shard_map(lambda v: collectives.ring_permute(v, AXES),
+                              mesh=mesh, in_specs=P(AXES), out_specs=P(AXES),
+                              axis_names=set(AXES), check_vma=False))
+    with jax.sharding.set_mesh(mesh):
+        np.testing.assert_array_equal(np.asarray(g(jnp.asarray(x))), expect)
+    print("  OK ring_permute tuple-axis rotation")
 
 
-def main():
+def main(parts=PARTS):
+    """Run the named halves (``simple``, ``streamed``); the test runs them as
+    two processes side by side."""
     assert jax.device_count() == 8, jax.device_count()
-    mesh = compat.make_mesh((2, 2, 2), ("pod", "data", "model"))
+    mesh = make_mesh((2, 2, 2), ("pod", "data", "model"))
     comp = CompressionConfig(compressor="sparsign",
                              budget=BudgetConfig(kind="fixed", value=2.0),
                              server="majority_vote")
     lr = LrSchedule(base=0.01)
 
+    # qsgd8 on the pack8 wire vs its decoded-psum oracle stream (the FedCom
+    # 8-bit baseline, Appendix B): vote_impl=psum negotiates the fp32 decoded
+    # wire, allgather_packed the 1 B/coord pack8 gather — same round bitwise
+    comp_q = CompressionConfig(compressor="qsgd8",
+                               budget=BudgetConfig(kind="fixed", value=1.0),
+                               server="mean")
+
+    # sparsign_golomb: same Def. 1 compressor, entropy-coded uplink. The psum
+    # wire negotiates plain int8 votes (a fabric psum cannot reduce
+    # variable-length byte streams — engine.wire_payload_format's fallback)
+    # and is the oracle stream; allgather_packed rides the Golomb/RLE coded
+    # byte wire (fused sparsign->coded-stream kernel + in-kernel decode-sum
+    # on the interpret backend). Bitwise equality across them is the
+    # acceptance check that the sub-2-bit wire is lossless end-to-end.
+    comp_g = CompressionConfig(
+        compressor="sparsign_golomb",
+        budget=BudgetConfig(kind="target_sparsity", value=0.05),
+        server="majority_vote")
+
+    if "simple" in parts:
+        check_simple(mesh, comp, comp_q, comp_g, lr)
+    if "streamed" in parts:
+        check_streamed(mesh, comp, comp_q, comp_g, lr)
+
+
+def check_simple(mesh, comp, comp_q, comp_g, lr):
     cfg_s = get_config("qwen1.5-4b", smoke=True)
     model_s = Model(cfg_s)
     params_s = model_s.init(jax.random.PRNGKey(0))
@@ -229,28 +247,11 @@ def main():
                    make_batch(cfg_s, 8, 16), comp_n, lr)
         print(f"OK {name} wires bitwise-equal (3 wires x 2 backends)")
 
-    # qsgd8 on the pack8 wire vs its decoded-psum oracle stream (the FedCom
-    # 8-bit baseline, Appendix B): vote_impl=psum negotiates the fp32 decoded
-    # wire, allgather_packed the 1 B/coord pack8 gather — same round bitwise
-    comp_q = CompressionConfig(compressor="qsgd8",
-                               budget=BudgetConfig(kind="fixed", value=1.0),
-                               server="mean")
     print("simple mode (qsgd8 / mean — decoded-psum oracle vs pack8 gather):")
     check_mode("simple", mesh, model_s, params_s, make_batch(cfg_s, 8, 16),
                comp_q, lr, wires=("psum", "allgather_packed"))
     print("OK qsgd8 pack8 wire bitwise-equal to the decoded psum (2 backends)")
 
-    # sparsign_golomb: same Def. 1 compressor, entropy-coded uplink. The psum
-    # wire negotiates plain int8 votes (a fabric psum cannot reduce
-    # variable-length byte streams — engine.wire_payload_format's fallback)
-    # and is the oracle stream; allgather_packed rides the Golomb/RLE coded
-    # byte wire (fused sparsign->coded-stream kernel + in-kernel decode-sum
-    # on the interpret backend). Bitwise equality across them is the
-    # acceptance check that the sub-2-bit wire is lossless end-to-end.
-    comp_g = CompressionConfig(
-        compressor="sparsign_golomb",
-        budget=BudgetConfig(kind="target_sparsity", value=0.05),
-        server="majority_vote")
     print("simple mode (sparsign_golomb — int8-psum oracle vs golomb gather):")
     check_mode("simple", mesh, model_s, params_s, make_batch(cfg_s, 8, 16),
                comp_g, lr, wires=("psum", "hier", "allgather_packed"))
@@ -259,8 +260,8 @@ def main():
     # ring-pipelined gather vs the monolithic all_gather (simple mode): the
     # integer wires pin bitwise, pack8 pins deterministic + allclose; the
     # bucketed variants chunk the multi-leaf bucket buffers (multi-chunk ring)
-    print("ring_permute old-jax fallback:")
-    check_ring_permute_fallback(mesh)
+    print("ring_permute:")
+    check_ring_permute(mesh)
     batch_s = make_batch(cfg_s, 8, 16)
     print("simple mode ring (sparsign pack2):")
     check_ring("simple", mesh, model_s, params_s, batch_s, comp, lr)
@@ -278,6 +279,8 @@ def main():
     print("OK simple-mode ring == monolithic (3 wires x 2 backends, "
           "per-leaf + bucketed)")
 
+
+def check_streamed(mesh, comp, comp_q, comp_g, lr):
     cfg_t = get_config("qwen2-moe-a2.7b", smoke=True)
     model_t = Model(cfg_t)
     params_t = model_t.init(jax.random.PRNGKey(0))
@@ -327,4 +330,4 @@ def main():
 
 
 if __name__ == "__main__":
-    main()
+    main(tuple(sys.argv[1:]) or PARTS)

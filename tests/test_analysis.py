@@ -125,7 +125,6 @@ def test_no_hbm_intermediate_limit_semantics():
 # ---------------------------------------------------------------------------
 
 def test_census_byte_math_on_shard_map_program():
-    from repro.dist import compat
     from repro.launch.mesh import make_host_mesh
 
     mesh = make_host_mesh(1, 1)
@@ -138,8 +137,8 @@ def test_census_byte_math_on_shard_map_program():
         g = jax.lax.all_gather(v, ("data",), axis=0, tiled=False)
         return tot, mx, g
 
-    fn = compat.shard_map(body, mesh=mesh, in_specs=(P(), P()),
-                          out_specs=(P(), P(), P()), check_vma=False)
+    fn = jax.shard_map(body, mesh=mesh, in_specs=(P(), P()),
+                       out_specs=(P(), P(), P()), check_vma=False)
     closed = jax.make_jaxpr(fn)(jnp.zeros((n,), jnp.int8),
                                 jnp.zeros((), jnp.float32))
     census = collective_census(closed)
@@ -171,7 +170,7 @@ def test_census_ppermute_ring_math():
     """ONE traced ppermute (the ring gather's hop primitive, while-looped at
     trips=1) bills as an (M-1)-hop ring of its operand."""
     from repro.analysis.jaxpr_audit import CollectiveRecord
-    from repro.dist import collectives, compat
+    from repro.dist import collectives
     from repro.launch.mesh import make_host_mesh
 
     rec = CollectiveRecord(primitive="ppermute", axes=("data",),
@@ -184,9 +183,9 @@ def test_census_ppermute_ring_math():
     mesh = make_host_mesh(1, 1)
     P = jax.sharding.PartitionSpec
     n = 2048
-    fn = compat.shard_map(lambda v: collectives.ring_permute(v, ("data",)),
-                          mesh=mesh, in_specs=P(), out_specs=P(),
-                          check_vma=False)
+    fn = jax.shard_map(lambda v: collectives.ring_permute(v, ("data",)),
+                       mesh=mesh, in_specs=P(), out_specs=P(),
+                       check_vma=False)
     census = collective_census(jax.make_jaxpr(fn)(jnp.zeros((n,), jnp.int8)))
     assert census.counts() == {"ppermute": 1}
     assert census.payload_bytes({"data": 16}) == pytest.approx(15 * n)

@@ -139,10 +139,9 @@ def test_as_rows_preserves_flat_index():
 # ---------------------------------------------------------------------------
 
 def _run(mode, **kw):
-    from repro.dist import compat
 
     step, state, batch, model, mesh, _ = drivers.build_mode_step(mode, **kw)
-    with compat.set_mesh(mesh):
+    with jax.sharding.set_mesh(mesh):
         out, metrics = step(state, batch)
     leaves = [np.asarray(x) for x in jax.tree_util.tree_leaves(out.params)]
     return leaves, metrics
@@ -162,7 +161,6 @@ def test_bucketed_per_slot_quorum_attribution():
     """Per-leaf quorum must address the right slot through the bucket: with a
     one-worker vote in {-1, 0, +1}, quorum=2 freezes exactly the leaves it is
     assigned to while quorum=1 leaves keep stepping."""
-    from repro.dist import compat
     from repro.train.state import LrSchedule, init_state
     from repro.train.step_simple import TrainStepConfig, build_train_step
 
@@ -184,7 +182,7 @@ def test_bucketed_per_slot_quorum_attribution():
                                backend="interpret", bucketed=bucketed)
         step = build_train_step(model, scfg, mesh)
         state = init_state(params, server=server, seed=7)
-        with compat.set_mesh(mesh):
+        with jax.sharding.set_mesh(mesh):
             out, _ = step(state, batch)
         outs.append(out.params)
     for a, b in zip(jax.tree_util.tree_leaves(outs[0]),

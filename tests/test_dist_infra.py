@@ -11,7 +11,6 @@ from benchmarks.bench_collectives import wire_model
 from benchmarks.bench_roofline import analytic_cell
 from repro.configs.registry import ARCH_IDS, get_config
 from repro.configs.shapes import SHAPES, all_cells
-from repro.dist.compat import abstract_mesh
 from repro.dist.sharding import logical_to_spec, sanitize_spec
 from repro.launch import hlo_stats
 
@@ -46,7 +45,7 @@ def test_hlo_parser_group_formats():
 @pytest.fixture(scope="module")
 def mesh16():
     # abstract-shaped mesh over 1 device is fine for spec math only
-    return abstract_mesh((16, 16), ("data", "model"))
+    return jax.sharding.AbstractMesh((16, 16), ("data", "model"))
 
 
 def test_sanitize_spec_nulls_nondividing(mesh16):

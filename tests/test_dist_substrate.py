@@ -8,14 +8,13 @@ from jax.sharding import NamedSharding, PartitionSpec as P
 
 from conftest import run_mdev
 
-from repro.dist.compat import abstract_mesh
 from repro.dist.sharding import (ACT_RULES_SERVE, ACT_RULES_TRAIN, TP_RULES,
                                  cache_shardings_tree, logical_to_spec,
                                  sanitize_spec, tp_param_shardings)
 
 @pytest.fixture(scope="module")
 def mesh16():
-    return abstract_mesh((16, 16), ("data", "model"))
+    return jax.sharding.AbstractMesh((16, 16), ("data", "model"))
 
 
 # ---------------------------------------------------------------------------
@@ -27,7 +26,7 @@ def test_sanitize_zero_dim_replicates(mesh16):
 
 
 def test_sanitize_size_one_axis_kept():
-    m = abstract_mesh((1, 16), ("data", "model"))
+    m = jax.sharding.AbstractMesh((1, 16), ("data", "model"))
     # a size-1 mesh axis divides everything: placement kept (it's a no-op)
     assert sanitize_spec(P("data", "model"), (7, 32), m) == P("data", "model")
 

@@ -107,7 +107,6 @@ def test_compress_leaf_shared_linf_mapped_context_is_loud():
     per-worker local norm — that degrade IS the TernGrad drift PR 4 killed.
     Outside a mesh the single-worker degrade stays available (public API)."""
     from jax.sharding import PartitionSpec as P
-    from repro.dist import compat
     from repro.launch.mesh import make_host_mesh
 
     g = jnp.asarray(np.random.RandomState(11).randn(64), jnp.float32)
@@ -120,10 +119,10 @@ def test_compress_leaf_shared_linf_mapped_context_is_loud():
     def body(x):
         return engine.compress_leaf(x, _cfg("terngrad"), 3, backend="jnp").values
 
-    mapped = compat.shard_map(body, mesh=mesh, in_specs=(P(),), out_specs=P(),
-                              axis_names={"data"}, check_vma=False)
+    mapped = jax.shard_map(body, mesh=mesh, in_specs=(P(),), out_specs=P(),
+                           axis_names={"data"}, check_vma=False)
     with pytest.raises(ValueError, match="shared_linf"):
-        with compat.set_mesh(mesh):
+        with jax.sharding.set_mesh(mesh):
             jax.jit(mapped)(g)
 
     # supplying shared_linf inside the same mapped context is fine
@@ -133,10 +132,10 @@ def test_compress_leaf_shared_linf_mapped_context_is_loud():
         return engine.compress_leaf(x, _cfg("terngrad"), 3, backend="jnp",
                                     shared_linf=shared).values
 
-    mapped_ok = compat.shard_map(body_ok, mesh=mesh, in_specs=(P(),),
-                                 out_specs=P(), axis_names={"data"},
-                                 check_vma=False)
-    with compat.set_mesh(mesh):
+    mapped_ok = jax.shard_map(body_ok, mesh=mesh, in_specs=(P(),),
+                              out_specs=P(), axis_names={"data"},
+                              check_vma=False)
+    with jax.sharding.set_mesh(mesh):
         out = jax.jit(mapped_ok)(g)
     assert out.shape == g.shape
 

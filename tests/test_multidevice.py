@@ -14,14 +14,44 @@ devices (the main pytest process must keep jax at 1 device for the smoke tests).
                            vote (4- vs 2-worker fleets on identical data).
 """
 
+import concurrent.futures
+
 import pytest
 
 from conftest import run_mdev as _run
 
+#: test -> the check processes whose output it reads: (script, args, timeout)
+RUNS = {
+    "test_simple_step_equivalence_and_variants": [("check_step_simple.py", (), 1200)],
+    "test_streamed_step_equivalence": [("check_step_streamed.py", (), 1200)],
+    "test_wire_equivalence_all_modes": [("check_wires.py", ("simple",), 2400),
+                                        ("check_wires.py", ("streamed",), 2400)],
+    "test_fault_tolerance_and_elastic": [("check_fault_tolerance.py", (), 1200)],
+}
+
+
+@pytest.fixture(scope="module")
+def mdev(request):
+    """Starts the check processes of every selected test of this file at once
+    (they are independent), so the file takes about as long as the slowest
+    check rather than the sum; each test then reads its own outputs."""
+    selected = {item.name for item in request.session.items
+                if item.module is request.module}
+    runs = [(name, run) for name, rs in RUNS.items() if name in selected for run in rs]
+    with concurrent.futures.ThreadPoolExecutor(max_workers=max(1, len(runs))) as pool:
+        futures = {}
+        for name, (script, args, timeout) in runs:
+            futures.setdefault(name, []).append(pool.submit(_run, script, timeout, args))
+        yield futures
+
+
+def _output(mdev, request) -> str:
+    return "".join(f.result() for f in mdev[request.node.name])
+
 
 @pytest.mark.slow
-def test_simple_step_equivalence_and_variants():
-    out = _run("check_step_simple.py")
+def test_simple_step_equivalence_and_variants(mdev, request):
+    out = _output(mdev, request)
     assert "OK simple-step == 4-worker oracle" in out
     assert "OK engine interpret backend == pre-refactor oracle" in out
     assert "OK EF server" in out
@@ -29,23 +59,23 @@ def test_simple_step_equivalence_and_variants():
 
 
 @pytest.mark.slow
-def test_streamed_step_equivalence():
-    out = _run("check_step_streamed.py")
+def test_streamed_step_equivalence(mdev, request):
+    out = _output(mdev, request)
     assert "0/" in out and "coords differ" in out
     assert "OK FSDP sharding" in out
     assert "OK streamed EF" in out
 
 
 @pytest.mark.slow
-def test_wire_equivalence_all_modes():
-    out = _run("check_wires.py", timeout=2400)
+def test_wire_equivalence_all_modes(mdev, request):
+    out = _output(mdev, request)
     assert "OK simple-mode wires bitwise-equal (3 wires x 2 backends)" in out
     assert "OK streamed-mode wires bitwise-equal (3 wires x 2 backends)" in out
 
 
 @pytest.mark.slow
-def test_fault_tolerance_and_elastic():
-    out = _run("check_fault_tolerance.py")
+def test_fault_tolerance_and_elastic(mdev, request):
+    out = _output(mdev, request)
     assert "OK crash/restart" in out
     assert "OK elastic" in out
     for tag in ("votes/psum", "votes/gather", "pack8/gather", "decoded/psum"):

@@ -190,14 +190,14 @@ def _tiny_batch(vocab, b=2, s=8, seed=0):
 
 
 def _one_step(model, params, batch, mesh, comp, **cfg_kw):
-    from repro.dist import compat
+    from repro.launch.mesh import make_mesh
     from repro.train.state import LrSchedule, init_state
     from repro.train.step_simple import TrainStepConfig, build_train_step
     scfg = TrainStepConfig(compression=comp, lr=LrSchedule(base=0.05),
                            worker_axes=("data",), donate=False, **cfg_kw)
     step = build_train_step(model, scfg, mesh)
     state = init_state(params, server=comp.server, seed=7)
-    with compat.set_mesh(mesh):
+    with jax.sharding.set_mesh(mesh):
         out, metrics = step(state, batch)
     return jax.tree_util.tree_map(np.asarray, out.params), metrics
 
@@ -243,9 +243,9 @@ def test_elastic_full_participation_bitwise_equals_legacy(mode, compressor,
 # ---------------------------------------------------------------------------
 
 def _gather_fn(masked: bool):
-    from repro.dist import compat
+    from repro.launch.mesh import make_mesh
     from jax.sharding import PartitionSpec as P
-    mesh = compat.make_mesh((1,), ("data",))
+    mesh = make_mesh((1,), ("data",))
 
     def inner(x, m):
         msg = x.astype(jnp.int8)
@@ -254,8 +254,8 @@ def _gather_fn(masked: bool):
         return jax.lax.all_gather(msg, "data")
 
     def fn(x, m):
-        return compat.shard_map(inner, mesh=mesh, in_specs=(P("data"), P()),
-                                out_specs=P(None), check_vma=False)(x, m)
+        return jax.shard_map(inner, mesh=mesh, in_specs=(P("data"), P()),
+                             out_specs=P(None), check_vma=False)(x, m)
 
     return mesh, fn
 
@@ -265,14 +265,14 @@ def test_masked_payload_zero_rule_blocks_unmasked_gather():
     its producer chain) must produce exactly one blocking finding; the
     jnp.where-masked twin must pass clean."""
     from repro.analysis.jaxpr_audit import MaskedPayloadZero
-    from repro.dist import compat
+    from repro.launch.mesh import make_mesh
     x = jnp.ones((8, 128), jnp.float32)
     m = jnp.bool_(True)
     rule = MaskedPayloadZero()
     mesh, bad = _gather_fn(masked=False)
-    with compat.set_mesh(mesh):
+    with jax.sharding.set_mesh(mesh):
         findings = rule.check("unmasked", bad, x, m)
     assert len(findings) == 1 and "no participation mask" in findings[0].message
     mesh, good = _gather_fn(masked=True)
-    with compat.set_mesh(mesh):
+    with jax.sharding.set_mesh(mesh):
         assert rule.check("masked", good, x, m) == []

@@ -14,7 +14,7 @@ import pytest
 from repro.core import engine
 from repro.core.algorithm import CompressionConfig
 from repro.core.budgets import BudgetConfig
-from repro.dist import bucketing, collectives, compat
+from repro.dist import bucketing, collectives
 from repro.kernels import common
 from repro.kernels.pack2bit.ops import pack2bit_op
 from repro.kernels.pack8.ops import qsgd8_pack8_op
@@ -241,9 +241,11 @@ def _m1_exchange(wire, payload, n, scale=None):
     def f(p):
         return wire.exchange(p, n, (n,), scale=scale)
 
-    g = compat.shard_map(f, mesh=mesh, in_specs=P(), out_specs=P(),
-                         axis_names={"data"}, check_vma=False)
-    with compat.set_mesh(mesh):
+    # jitted, as the trainers run it: jax 0.9's eager partial-manual
+    # shard_map with check_vma=False rejects its own P() out_specs
+    g = jax.jit(jax.shard_map(f, mesh=mesh, in_specs=P(), out_specs=P(),
+                              axis_names={"data"}, check_vma=False))
+    with jax.sharding.set_mesh(mesh):
         return np.asarray(g(payload))
 
 
@@ -316,7 +318,7 @@ def _one_step(model, params, batch, mesh, comp, **cfg_kw):
                            worker_axes=("data",), donate=False, **cfg_kw)
     step = build_train_step(model, scfg, mesh)
     state = init_state(params, server=comp.server, seed=7)
-    with compat.set_mesh(mesh):
+    with jax.sharding.set_mesh(mesh):
         out, metrics = step(state, batch)
     return jax.tree_util.tree_map(np.asarray, out.params), metrics
 

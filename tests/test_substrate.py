@@ -272,3 +272,51 @@ def test_participation_rate_and_determinism():
     hits2 = [bool(participation_mask(rs, 0, w, 0.3)) for w in range(2000)]
     assert hits == hits2
     assert bool(participation_mask(rs, 0, 5, 1.0)) is True
+
+
+# ---------------------------------------------------------------------------
+# Launcher: one executable per step config, compile-cache placement
+# ---------------------------------------------------------------------------
+
+@pytest.mark.parametrize("server,vote_impl", [("scaled_sign_ef", "psum"),
+                                              ("majority_vote", "allgather_packed")])
+def test_launcher_state_placement_reuses_step0_executable(server, vote_impl):
+    """The initial TrainState carries the placement the step returns, so the
+    second step hits the first step's executable instead of recompiling."""
+    from repro.launch import train
+
+    args = train.build_parser().parse_args(
+        ["--arch", "mamba2-370m", "--steps", "2", "--seq-len", "16",
+         "--server", server, "--vote-impl", vote_impl])
+    cfg, model, mesh, step, state, comp = train.build_everything(args)
+    batch_fn = train.batch_fn_for(cfg, args)
+    with jax.sharding.set_mesh(mesh):
+        for i in range(2):
+            state, metrics = step(state, batch_fn(i))
+    assert np.isfinite(float(metrics["loss"]))
+    assert step._cache_size() == 1
+
+
+@pytest.mark.parametrize("env_dir", [None, "from-env"])
+def test_compile_cache_directory(env_dir, tmp_path):
+    """$JAX_COMPILATION_CACHE_DIR when set, else the fixed <checkout>/.jax_cache.
+    Run in a child so this process's jax config stays untouched."""
+    import pathlib
+    import subprocess
+    import sys
+
+    env = {k: v for k, v in os.environ.items() if k != "JAX_COMPILATION_CACHE_DIR"}
+    root = pathlib.Path(__file__).resolve().parents[1]
+    env["JAX_PLATFORMS"] = "cpu"
+    env["PYTHONPATH"] = str(root / "src")
+    want = root / ".jax_cache"
+    if env_dir is not None:
+        want = tmp_path / env_dir
+        env["JAX_COMPILATION_CACHE_DIR"] = str(want)
+    code = ("import jax\n"
+            "from repro.launch.compile_cache import enable_compile_cache\n"
+            "print(enable_compile_cache())\n"
+            "print(jax.config.jax_compilation_cache_dir)\n")
+    out = subprocess.run([sys.executable, "-c", code], env=env, check=True,
+                         capture_output=True, text=True, timeout=120).stdout.split()
+    assert out == [str(want), str(want)]

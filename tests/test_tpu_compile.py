@@ -1,0 +1,149 @@
+"""Compile the TPU path for a described v5e, without a chip.
+
+Interpret mode cannot show what Mosaic refuses (casts, SMEM bitcasts, 8-bit
+compares, relayouts, auto-partitioned kernels); compiling with
+``interpret=False`` for a described ``v5e:2x2`` topology does. Every kernel
+that compiles is pinned here at real width (the 50280x1024 mamba2-370m
+embedding leaf, a 4-worker gather for the decode-sums), plus a whole train
+step of each wire ``chip_smoke.py`` drives, at smoke size. The Golomb
+kernels have no Mosaic lowering yet and are left out (ROADMAP S2).
+
+The topology is described inside a module fixture, never at import: only one
+process may load the TPU library, and it keeps it until it exits.
+"""
+
+import math
+import os
+
+import jax
+import jax.numpy as jnp
+import pytest
+from jax.sharding import NamedSharding, PartitionSpec as P, SingleDeviceSharding
+
+LEAF = (50280, 1024)
+WORKERS = 4
+
+
+@pytest.fixture(scope="module")
+def topo():
+    from jax.experimental import topologies
+    from jax.experimental.compilation_cache import compilation_cache
+
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    try:
+        desc = topologies.get_topology_desc(platform="tpu", topology_name="v5e:2x2")
+    except Exception as e:  # noqa: BLE001 — any failure means no TPU compiler here
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    # a described chip's compiles are written to the persistent cache but
+    # cannot be read back without one: keep the cache off around them
+    was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    yield desc
+    jax.config.update("jax_enable_compilation_cache", was)
+    compilation_cache.reset_cache()
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
+    return SingleDeviceSharding(topo.devices[0])
+
+
+def _kernel_case(name, one_chip):
+    """(fn, operand shapes) of one kernel entry point with interpret=False."""
+    import repro.core  # noqa: F401 — import order: the kernels' ops import core
+    from repro.kernels import common
+    from repro.kernels.ef_server.ops import ef_server_op
+    from repro.kernels.pack2bit.ops import (pack2bit_op, unpack2bit_op,
+                                            unpack2bit_sum_op, unpack2bit_wsum_op)
+    from repro.kernels.pack8.ops import qsgd8_pack8_op, unpack8_sum_op
+    from repro.kernels.sparsign.ops import sparsign_op
+    from repro.kernels.sparsign_pack2bit.ops import sparsign_pack2bit_op
+    from repro.kernels.ternary import ops as ternary
+    from repro.kernels.vote_update.ops import vote_update_op, weighted_vote_update_op
+
+    def s(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    n = math.prod(LEAF)
+    rows = common.canonical_rows(n)
+    f32, bf16 = jnp.float32, jnp.bfloat16
+    g, g16, scalar, seed = s(LEAF, f32), s(LEAF, bf16), s((), f32), s((), jnp.uint32)
+    packed = s((WORKERS, rows, common.LANES // 4), jnp.uint8)
+    weights = s((WORKERS,), f32)
+
+    def compressor(op, grad=g):
+        return (lambda x, p, sd: op(x, p, sd, 0, interpret=False)), (grad, scalar, seed)
+
+    return {
+        "sparsign": compressor(sparsign_op),
+        "sparsign_bf16": compressor(sparsign_op, g16),
+        "sparsign_pack2bit": compressor(sparsign_pack2bit_op),
+        "sign_pack2bit": compressor(ternary.sign_pack2bit_op),
+        "noisy_sign_pack2bit": compressor(ternary.noisy_sign_pack2bit_op),
+        "stochastic_ternary": compressor(ternary.stochastic_ternary_op),
+        "qsgd8_pack8": compressor(qsgd8_pack8_op),
+        "pack2bit": (lambda t: pack2bit_op(t, interpret=False), (s(LEAF, jnp.int8),)),
+        "unpack2bit": (lambda p: unpack2bit_op(p, n, LEAF, interpret=False),
+                       (s((rows, common.LANES // 4), jnp.uint8),)),
+        "unpack2bit_sum": (lambda p: unpack2bit_sum_op(p, n, LEAF, interpret=False),
+                           (packed,)),
+        "unpack2bit_wsum": (
+            lambda p, w: unpack2bit_wsum_op(p, w, n, LEAF, interpret=False),
+            (packed, weights)),
+        "unpack8_sum": (
+            lambda p, w: unpack8_sum_op(p, w, n, LEAF, interpret=False),
+            (s((WORKERS, rows, common.LANES), jnp.int8), weights)),
+        "vote_update": (
+            lambda w, v, e: vote_update_op(w, v, e, quorum=1, interpret=False),
+            (g16, s(LEAF, jnp.int8), scalar)),
+        "weighted_vote_update": (
+            lambda w, v, t, e: weighted_vote_update_op(w, v, t, e, q_frac=0.5,
+                                                       interpret=False),
+            (g16, g, scalar, scalar)),
+        "ef_server": (lambda d, e: ef_server_op(d, e, interpret=False), (g, g)),
+    }[name]
+
+
+KERNELS = ["sparsign", "sparsign_bf16", "sparsign_pack2bit", "sign_pack2bit",
+           "noisy_sign_pack2bit", "stochastic_ternary", "qsgd8_pack8",
+           "pack2bit", "unpack2bit", "unpack2bit_sum", "unpack2bit_wsum",
+           "unpack8_sum", "vote_update", "weighted_vote_update", "ef_server"]
+
+
+@pytest.mark.parametrize("name", KERNELS)
+def test_kernel_compiles_for_v5e(name, one_chip):
+    fn, operands = _kernel_case(name, one_chip)
+    compiled = jax.jit(fn).lower(*operands).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+
+
+@pytest.mark.parametrize("server,vote_impl", [("scaled_sign_ef", "psum"),
+                                              ("majority_vote", "allgather_packed")])
+def test_train_step_compiles_for_v5e(server, vote_impl, topo):
+    """The simple trainer's whole step with the pallas backend on a (1, 1)
+    mesh: the kernels sit inside the step's shard_map, where an axis left to
+    GSPMD would make Mosaic refuse them."""
+    from repro.configs.registry import get_config
+    from repro.core.algorithm import CompressionConfig
+    from repro.launch.mesh import make_mesh
+    from repro.models.model import Model
+    from repro.train.state import LrSchedule, init_state
+    from repro.train.step_simple import TrainStepConfig, build_train_step
+
+    mesh = make_mesh((1, 1), ("data", "model"), devices=topo.devices[:1])
+    model = Model(get_config("mamba2-370m", smoke=True))
+    comp = CompressionConfig(compressor="sparsign", server=server)
+    step = build_train_step(model, TrainStepConfig(
+        compression=comp, lr=LrSchedule(base=1e-3), vote_impl=vote_impl,
+        backend="pallas"), mesh)
+    state = jax.eval_shape(lambda: init_state(model.init(jax.random.PRNGKey(0)),
+                                              server=server, seed=0))
+    rep = NamedSharding(mesh, P())
+    state = jax.tree_util.tree_map(
+        lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=rep), state)
+    batch = {k: jax.ShapeDtypeStruct((8, 64), jnp.int32, sharding=rep)
+             for k in ("inputs", "labels", "positions")}
+    with jax.sharding.set_mesh(mesh):
+        compiled = step.lower(state, batch).compile()
+    assert "tpu_custom_call" in compiled.as_text()

@@ -234,7 +234,6 @@ def _tiny_batch(vocab, b=2, s=8, seed=0):
 
 
 def _one_step(model, params, batch, mesh, comp=None, **cfg_kw):
-    from repro.dist import compat
     from repro.train.state import LrSchedule, init_state
     from repro.train.step_simple import TrainStepConfig, build_train_step
     if comp is None:
@@ -245,7 +244,7 @@ def _one_step(model, params, batch, mesh, comp=None, **cfg_kw):
                            worker_axes=("data",), donate=False, **cfg_kw)
     step = build_train_step(model, scfg, mesh)
     state = init_state(params, server=comp.server, seed=7)
-    with compat.set_mesh(mesh):
+    with jax.sharding.set_mesh(mesh):
         out, metrics = step(state, batch)
     return jax.tree_util.tree_map(np.asarray, out.params), metrics
 
