@@ -29,17 +29,19 @@ Elastic-participation columns (``elastic_real`` / ``weight_side`` /
 ``weight_tax``): the weighted vote's packed gather ships the same payload plus
 one (1,) f32 participation weight per peer per exchange — weight_side =
 launches x (M-1) x 4 B, asserted to be EXACTLY the elastic-vs-legacy ledger
-delta. The step-time section adds ``elastic_full`` (weighted exchange, full
+delta. The step section adds ``elastic_full`` (weighted exchange, full
 participation) and ``elastic_mask50`` (50% per-round report dropout — masked
 payloads are exact zeros but every byte still rides the fixed-shape wire).
 
-The step-time section times real train steps (per-leaf vs bucketed wire, both
-trainers, plus ``ring_*`` chunked-ppermute configs) on forced host devices and
-writes the tracked ``BENCH_collectives.json`` at the repo root (``--quick``
-writes ``BENCH_collectives.quick.json`` — the CI smoke artifact — so it can't
+The step section runs one real train step per configuration (per-leaf vs
+bucketed wire, both trainers, plus ``ring_*`` chunked-ppermute configs) on
+forced host devices and records the step's own counts (wire bytes, gather HBM
+bytes, participation); nothing is timed. It writes the tracked
+``BENCH_collectives.json`` at the repo root (``--quick`` writes
+``BENCH_collectives.quick.json`` — the CI smoke artifact — so it can't
 clobber the baseline).
 
-  python -m benchmarks.bench_collectives            # full table + step times
+  python -m benchmarks.bench_collectives            # full table + step counts
   python -m benchmarks.bench_collectives --quick    # CI smoke
 """
 
@@ -53,11 +55,11 @@ import time
 from collections import Counter
 from pathlib import Path
 
-# before any jax backend init: the step-time section wants real host devices
+# before any jax backend init: the step section wants real host devices
 # (harmless if another module initialized jax first — the section falls back)
 os.environ.setdefault("XLA_FLAGS", "--xla_force_host_platform_device_count=8")
 
-from benchmarks.common import csv_header, csv_row, timed
+from benchmarks.common import csv_header, csv_row
 from repro.configs.registry import ARCH_IDS, get_config, trainer_mode
 
 ROOT = Path(__file__).resolve().parents[1]
@@ -337,10 +339,10 @@ def wire_model(n_params: int, mode: str, n_data: int = 16, n_pod: int = 1,
 
 
 # ---------------------------------------------------------------------------
-# step-level wire time: per-leaf vs bucketed, both trainers
+# step-level wire counts: per-leaf vs bucketed, both trainers
 # ---------------------------------------------------------------------------
 
-def _time_simple_steps(modes, records, repeats: int):
+def _simple_step_counts(modes, records):
     import jax
 
     from repro.analysis import drivers
@@ -351,23 +353,20 @@ def _time_simple_steps(modes, records, repeats: int):
             step, state, batch, model, mesh, _ = drivers.build_mode_step(
                 mode, bucketed=bucketed)
             with jax.sharding.set_mesh(mesh):
-                (_, metrics), dt = timed(
-                    lambda: jax.block_until_ready(step(state, batch)),
-                    repeats=repeats)
+                _, metrics = step(state, batch)
             records.append({
                 "case": f"step_simple/{mode}/{'bucketed' if bucketed else 'per_leaf'}",
                 "trainer": "simple", "wire_mode": mode, "bucketed": bucketed,
-                "ms_per_step": dt * 1e3,
                 "wire_bytes_per_device": float(metrics["wire_bytes_per_device"]),
                 "gather_hbm_bytes": float(metrics["gather_hbm_bytes"]),
             })
-            csv_row([records[-1]["case"], f"{dt*1e3:.2f}",
+            csv_row([records[-1]["case"],
                      f"{records[-1]['wire_bytes_per_device']:.0f}",
                      f"{records[-1]['gather_hbm_bytes']:.0f}"])
 
 
-def _time_elastic_steps(records, repeats: int):
-    """Elastic-participation timing rows on the votes wire: the weighted
+def _elastic_step_counts(records):
+    """Elastic-participation rows on the votes wire: the weighted
     exchange at full participation, and the chaos configuration (50%%
     per-round report dropout) where half the fleet's payloads are masked to
     exact zeros but — SPMD ships fixed shapes — every byte still rides."""
@@ -383,23 +382,20 @@ def _time_elastic_steps(records, repeats: int):
         step, state, batch, model, mesh, _ = drivers.build_mode_step(
             "votes", participation=part)
         with jax.sharding.set_mesh(mesh):
-            (_, metrics), dt = timed(
-                lambda: jax.block_until_ready(step(state, batch)),
-                repeats=repeats)
+            _, metrics = step(state, batch)
         records.append({
             "case": f"step_simple/votes/{tag}",
             "trainer": "simple", "wire_mode": "votes", "bucketed": False,
-            "ms_per_step": dt * 1e3,
             "wire_bytes_per_device": float(metrics["wire_bytes_per_device"]),
             "gather_hbm_bytes": float(metrics["gather_hbm_bytes"]),
             "participated": float(metrics["participated"]),
         })
-        csv_row([records[-1]["case"], f"{dt*1e3:.2f}",
+        csv_row([records[-1]["case"],
                  f"{records[-1]['wire_bytes_per_device']:.0f}",
                  f"{records[-1]['gather_hbm_bytes']:.0f}"])
 
 
-def _time_streamed_steps(modes, records, repeats: int):
+def _streamed_step_counts(modes, records):
     import jax
     import jax.numpy as jnp
     import numpy as np
@@ -416,7 +412,7 @@ def _time_streamed_steps(modes, records, repeats: int):
 
     n_dev = jax.device_count()
     if n_dev < 2:
-        print("# streamed step timing skipped: needs >= 2 devices "
+        print("# streamed steps skipped: need >= 2 devices "
               f"(have {n_dev})")
         return
     data = 4 if n_dev >= 8 else 2
@@ -450,18 +446,15 @@ def _time_streamed_steps(modes, records, repeats: int):
                 ring_chunk_rows=ring_rows), mesh)
             state = init_state(params, server=server, seed=42)
             with jax.sharding.set_mesh(mesh):
-                (_, metrics), dt = timed(
-                    lambda: jax.block_until_ready(step(state, batch)),
-                    repeats=repeats)
+                _, metrics = step(state, batch)
             records.append({
                 "case": f"step_streamed/{mode}/"
                         f"{'double_buffered' if bucketed else 'per_leaf'}",
                 "trainer": "streamed", "wire_mode": mode, "bucketed": bucketed,
-                "ms_per_step": dt * 1e3,
                 "wire_bytes_per_device": float(metrics["wire_bytes_per_device"]),
                 "gather_hbm_bytes": float(metrics["gather_hbm_bytes"]),
             })
-            csv_row([records[-1]["case"], f"{dt*1e3:.2f}",
+            csv_row([records[-1]["case"],
                      f"{records[-1]['wire_bytes_per_device']:.0f}",
                      f"{records[-1]['gather_hbm_bytes']:.0f}"])
 
@@ -538,18 +531,16 @@ def main(fast: bool = False, out: Path | None = None):
             "weight_side_bytes": wside,
         })
 
-    print("\n# step time: per-leaf vs bucketed wire "
+    print("\n# step counts: per-leaf vs bucketed wire "
           f"(jax backend={jax.default_backend()}, {jax.device_count()} devices)")
-    csv_header(["case", "ms_per_step", "wire_bytes_per_device",
-                "gather_hbm_bytes"])
+    csv_header(["case", "wire_bytes_per_device", "gather_hbm_bytes"])
     modes = (("votes", "ring_pack2") if fast
              else ("votes", "scaled_votes", "pack8", "decoded",
                    "ring_pack2", "ring_pack8"))
-    repeats = 2 if fast else 3
     records: list[dict] = []
-    _time_simple_steps(modes, records, repeats)
-    _time_elastic_steps(records, repeats)
-    _time_streamed_steps(modes, records, repeats)
+    _simple_step_counts(modes, records)
+    _elastic_step_counts(records)
+    _streamed_step_counts(modes, records)
 
     doc = {
         "schema": 1,
@@ -561,17 +552,17 @@ def main(fast: bool = False, out: Path | None = None):
         "timestamp": time.strftime("%Y-%m-%dT%H:%M:%SZ", time.gmtime()),
         "note": ("ledger table bills the trainer's REAL exchange granularity "
                  "(streamed: n_repeats per-layer exchanges per block leaf); "
-                 "step times compare the per-leaf wire against the bucketed "
-                 "(simple) / double-buffered (streamed) wire on host devices "
-                 "— launch-count savings, not fabric bandwidth. Ring columns "
+                 "step rows give the per-leaf and the bucketed (simple) / "
+                 "double-buffered (streamed) wire's own counts from one host "
+                 "step; nothing is timed. Ring columns "
                  "are at collectives.DEFAULT_RING_CHUNK_ROWS: the ring moves "
                  "the same fabric bytes as the monolithic gather (asserted "
                  "via the traced ring census) but holds only ~2 chunks of "
-                 "payload instead of M exchanges' worth; ring_* step-time "
+                 "payload instead of M exchanges' worth; ring_* step "
                  "rows run the chunked ppermute wire and report its "
                  "gather_hbm_bytes metric. elastic_real/weight_side columns "
                  "bill the weighted exchange's (M-1)x4B-per-launch f32 weight "
-                 "side channel; elastic_* step rows time the weighted vote at "
+                 "side channel; elastic_* step rows run the weighted vote at "
                  "full participation and under 50% report dropout."),
         "ledger": table,
         "results": records,

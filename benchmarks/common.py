@@ -3,7 +3,6 @@
 from __future__ import annotations
 
 import sys
-import time
 
 from repro.core.algorithm import CompressionConfig
 from repro.core.budgets import BudgetConfig
@@ -19,15 +18,6 @@ def csv_header(cols, file=sys.stdout):
 
 def csv_row(vals, file=sys.stdout):
     print(",".join(str(v) for v in vals), file=file, flush=True)
-
-
-def timed(fn, *args, repeats: int = 3, **kw):
-    fn(*args, **kw)  # warmup/compile
-    t0 = time.perf_counter()
-    for _ in range(repeats):
-        out = fn(*args, **kw)
-    dt = (time.perf_counter() - t0) / repeats
-    return out, dt
 
 
 # The paper's §6 algorithm grid (Tables 1-2)

@@ -46,6 +46,7 @@ import jax.numpy as jnp
 from repro.core.budgets import BudgetConfig, resolve_budget
 from repro.core.compressors import (CompressedGrad, CompressorSpec,
                                     chunked_values, get_spec)
+from repro.core.scopes import SERVER, UPLINK, scoped
 from repro.kernels import common as kcommon
 from repro.kernels.ef_server.ops import ef_server_op
 from repro.kernels.ef_server.ref import ef_server_ref
@@ -293,6 +294,7 @@ def broadcast_quorum(quorum, like_tree):
 # Worker-side primitive
 # ---------------------------------------------------------------------------
 
+@scoped(UPLINK)
 def compress_leaf(
     g: jnp.ndarray,
     cfg: "CompressionConfig",
@@ -393,6 +395,7 @@ def compress_leaf(
     return CompressedGrad(values=vals, scale=msg_scale)
 
 
+@scoped(UPLINK)
 def compress_leaf_rows(
     g: jnp.ndarray,
     cfg: "CompressionConfig",
@@ -423,6 +426,7 @@ def compress_leaf_rows(
 # Server-side primitive
 # ---------------------------------------------------------------------------
 
+@scoped(SERVER)
 def server_apply(
     p: jnp.ndarray,
     vote_sum: jnp.ndarray,

@@ -69,6 +69,8 @@ from typing import Optional, Sequence, Tuple
 import jax
 import jax.numpy as jnp
 
+from repro.core.scopes import COUNTERS, EXCHANGE, SERVER, UPLINK, scoped
+
 
 VOTE_IMPLS = ("psum", "hier", "allgather_packed")
 
@@ -284,6 +286,7 @@ def vote_allgather_packed(votes: jnp.ndarray, axes: Sequence[str],
     return total.astype(_sum_dtype(int(n_workers)))
 
 
+@scoped(SERVER)
 def _packed_decode_sum(gathered: jnp.ndarray, size: int, shape,
                        *, backend: Optional[str]) -> jnp.ndarray:
     """(M, rows, q) gathered packed votes -> int32 vote sum in ``shape``,
@@ -298,6 +301,7 @@ def _packed_decode_sum(gathered: jnp.ndarray, size: int, shape,
     return unpack2bit_sum_op(gathered, size, shape, interpret=interpret)
 
 
+@scoped(SERVER)
 def _golomb_decode_sum(gathered: jnp.ndarray, size: int, shape, *, p: float,
                        backend: Optional[str]) -> jnp.ndarray:
     """(M, rows, 128) gathered entropy-coded payloads -> int32 vote sum in
@@ -312,6 +316,7 @@ def _golomb_decode_sum(gathered: jnp.ndarray, size: int, shape, *, p: float,
     return ungolomb_sum_op(gathered, size, shape, p=p, interpret=interpret)
 
 
+@scoped(SERVER)
 def _packed_decode_wsum(gathered: jnp.ndarray, weights: jnp.ndarray,
                         size: int, shape,
                         *, backend: Optional[str]) -> jnp.ndarray:
@@ -329,6 +334,7 @@ def _packed_decode_wsum(gathered: jnp.ndarray, weights: jnp.ndarray,
     return unpack2bit_wsum_op(gathered, weights, size, shape, interpret=interpret)
 
 
+@scoped(SERVER)
 def _golomb_decode_wsum(gathered: jnp.ndarray, weights: jnp.ndarray,
                         size: int, shape, *, p: float,
                         backend: Optional[str]) -> jnp.ndarray:
@@ -345,12 +351,14 @@ def _golomb_decode_wsum(gathered: jnp.ndarray, weights: jnp.ndarray,
 
 
 def _unpack8_op():
-    """Lazy accessor for the fused pack8 decode-sum op (kernels import at
-    call time, like every other kernel dispatch in this module)."""
+    """Lazy accessor for the fused pack8 decode-sum op, traced under the
+    server scope (kernels import at call time, like every other kernel
+    dispatch in this module)."""
     from repro.kernels.pack8.ops import unpack8_sum_op
-    return unpack8_sum_op
+    return scoped(SERVER)(unpack8_sum_op)
 
 
+@scoped(UPLINK)
 def decoded_message(values: jnp.ndarray, scale, mask, *, is_ternary: bool):
     """One worker's ``decoded``-mode message: decode locally (values * scale),
     zero non-participants. Returns ``(decoded fp32 message, masked nnz)`` —
@@ -360,14 +368,16 @@ def decoded_message(values: jnp.ndarray, scale, mask, *, is_ternary: bool):
     the bitwise pin between them depends on ONE decode definition."""
     dec = values.astype(jnp.float32) * scale
     dec = jnp.where(mask, dec, 0.0)
-    if is_ternary:
-        nnz = jnp.sum(jnp.abs(
-            jnp.where(mask, values, jnp.zeros((), values.dtype))).astype(jnp.float32))
-    else:
-        nnz = jnp.sum((dec != 0.0).astype(jnp.float32))
+    with jax.named_scope(COUNTERS):
+        if is_ternary:
+            nnz = jnp.sum(jnp.abs(
+                jnp.where(mask, values, jnp.zeros((), values.dtype))).astype(jnp.float32))
+        else:
+            nnz = jnp.sum((dec != 0.0).astype(jnp.float32))
     return dec, nnz
 
 
+@scoped(EXCHANGE)
 def decoded_exchange(values: jnp.ndarray, scale, mask, axes: Sequence[str],
                      *, is_ternary: bool):
     """The ``decoded`` wire mode, shared verbatim by both train modes: decode
@@ -379,6 +389,7 @@ def decoded_exchange(values: jnp.ndarray, scale, mask, axes: Sequence[str],
     return jax.lax.psum(dec, tuple(axes)), nnz
 
 
+@scoped(EXCHANGE)
 def decoded_exchange_bucket(payload: jnp.ndarray, axes: Sequence[str]) -> jnp.ndarray:
     """Bucketed ``decoded``-mode exchange: ONE fp32 psum of a whole bucket of
     pre-decoded, pre-masked messages (``decoded_message`` per leaf, assembled
@@ -505,7 +516,6 @@ def vote_allgather_packed8(payload: jnp.ndarray, scale, axes: Sequence[str],
     the kernel backends run the honest 1 B/coord gather.
     """
     from repro.kernels import common as kcommon
-    from repro.kernels.pack8.ops import unpack8_sum_op
 
     scale = jnp.asarray(scale, jnp.float32)
     if backend == "jnp":
@@ -514,7 +524,7 @@ def vote_allgather_packed8(payload: jnp.ndarray, scale, axes: Sequence[str],
     gathered = jax.lax.all_gather(payload, tuple(axes), axis=0, tiled=False)
     scales = jax.lax.all_gather(scale, tuple(axes), axis=0, tiled=False)
     interpret = (backend == "interpret") if backend is not None else None
-    return unpack8_sum_op(gathered, scales, size, shape, interpret=interpret)
+    return _unpack8_op()(gathered, scales, size, shape, interpret=interpret)
 
 
 # ---------------------------------------------------------------------------
@@ -674,15 +684,18 @@ class VoteWire:
         """Does this wire speak a packed canonical view (vs leaf-shaped votes)?"""
         return self.native_format != "int8"
 
+    @scoped(UPLINK)
     def mask_message(self, values: jnp.ndarray, mask) -> jnp.ndarray:
         """Zero a non-participating worker's message, in wire-native format
         (an all-zero packed byte decodes to four zero votes)."""
         return jnp.where(mask, values, jnp.zeros((), values.dtype))
 
+    @scoped(COUNTERS)
     def message_nnz(self, values: jnp.ndarray) -> jnp.ndarray:
         """Number of nonzero votes in one wire-native message (f32 scalar)."""
         return jnp.sum(jnp.abs(values).astype(jnp.float32))
 
+    @scoped(EXCHANGE)
     def exchange(self, values: jnp.ndarray, size: int, shape, *,
                  scale=None) -> jnp.ndarray:
         """Wire-native message -> integer vote sum of shape ``shape``.
@@ -704,6 +717,7 @@ class VoteWire:
                 f"elastic-participation path — pass participation= to "
                 f"make_vote_wire")
 
+    @scoped(EXCHANGE)
     def exchange_weighted(self, values: jnp.ndarray, size: int, shape, *,
                           weight, scale=None):
         """Elastic exchange: ``(sum_m w_m * votes_m, per-coordinate
@@ -723,6 +737,7 @@ class VoteWire:
                             tuple(self.axes))
         return wv, wtot
 
+    @scoped(EXCHANGE)
     def exchange_bucket(self, payload: jnp.ndarray, bucket, *, scale=None):
         """One bucket of wire-native messages -> per-leaf aggregates, ONE
         collective. ``payload`` is the assembled (rows, width) buffer
@@ -741,6 +756,7 @@ class VoteWire:
         return bucketing.split_bucket(
             vote_psum(payload, self.axes, self.n_workers), bucket)
 
+    @scoped(EXCHANGE)
     def exchange_bucket_weighted(self, payload: jnp.ndarray, bucket, *,
                                  weight, scale=None):
         """Bucketed elastic exchange: per-leaf ``(weighted vote sums,
@@ -835,6 +851,7 @@ class HierVoteWire(VoteWire):
 
     name = "hier"
 
+    @scoped(EXCHANGE)
     def exchange(self, values, size, shape, *, scale=None):
         if scale is not None:
             raise ValueError(
@@ -843,6 +860,7 @@ class HierVoteWire(VoteWire):
         return vote_psum_hier(values, self.axes[1], self.axes[0],
                               self.inner_size, self.outer_size)
 
+    @scoped(EXCHANGE)
     def exchange_bucket(self, payload, bucket, *, scale=None):
         if scale is not None:
             raise ValueError(
@@ -859,6 +877,7 @@ class HierVoteWire(VoteWire):
         # shape (intra-pod reduce, then the DCN hop)
         return jax.lax.psum(jax.lax.psum(x, self.axes[1]), self.axes[0])
 
+    @scoped(EXCHANGE)
     def exchange_weighted(self, values, size, shape, *, weight, scale=None):
         self._require_participation()
         if scale is not None:
@@ -871,6 +890,7 @@ class HierVoteWire(VoteWire):
             jnp.broadcast_to(w, shape).astype(jnp.float32))
         return wv, wtot
 
+    @scoped(EXCHANGE)
     def exchange_bucket_weighted(self, payload, bucket, *, weight, scale=None):
         self._require_participation()
         if scale is not None:
@@ -915,6 +935,7 @@ class PackedVoteWire(VoteWire):
     name = "allgather_packed"
     native_format = "pack2"
 
+    @scoped(COUNTERS)
     def message_nnz(self, values):
         # count nonzero 2-bit codes straight off the bytes: codes are {0,1,2},
         # so (b | b>>1) has bit 0 of each code set iff the code is nonzero
@@ -942,6 +963,7 @@ class PackedVoteWire(VoteWire):
                                           self.n_workers))
         return parts[0] if len(parts) == 1 else jnp.concatenate(parts)
 
+    @scoped(EXCHANGE)
     def exchange(self, values, size, shape, *, scale=None):
         if scale is not None:
             raise ValueError(
@@ -955,6 +977,7 @@ class PackedVoteWire(VoteWire):
         total = _packed_decode_sum(gathered, size, shape, backend=self.backend)
         return total.astype(_sum_dtype(self.n_workers))
 
+    @scoped(EXCHANGE)
     def exchange_bucket(self, payload, bucket, *, scale=None):
         """ONE all-gather of the whole packed bucket + one fused decode-sum
         over it, then split on the decoded stream. pack2 packs each canonical
@@ -1002,6 +1025,7 @@ class PackedVoteWire(VoteWire):
             wtot = wt if wtot is None else wtot
         return (parts[0] if len(parts) == 1 else jnp.concatenate(parts)), wtot
 
+    @scoped(EXCHANGE)
     def exchange_weighted(self, values, size, shape, *, weight, scale=None):
         self._require_participation()
         if scale is not None:
@@ -1019,6 +1043,7 @@ class PackedVoteWire(VoteWire):
                                  backend=self.backend)
         return wv, jnp.sum(wvec)
 
+    @scoped(EXCHANGE)
     def exchange_bucket_weighted(self, payload, bucket, *, weight, scale=None):
         self._require_participation()
         if scale is not None:
@@ -1099,6 +1124,7 @@ class Pack8Wire(VoteWire):
     name = "allgather_packed8"
     native_format = "pack8"
 
+    @scoped(COUNTERS)
     def message_nnz(self, values):
         # nonzero LEVELS, not their magnitudes: |level| would overweight
         # large coordinates in the nnz_frac metric
@@ -1107,6 +1133,7 @@ class Pack8Wire(VoteWire):
     def _interpret(self):
         return (self.backend == "interpret") if self.backend is not None else None
 
+    @scoped(EXCHANGE)
     def exchange(self, values, size, shape, *, scale=None):
         if scale is None:
             raise ValueError(
@@ -1123,22 +1150,22 @@ class Pack8Wire(VoteWire):
         ledger's ``ring_chunks`` factor), each arriving slice dequantize-
         summed through the fused kernel at M=1."""
         from repro.kernels import common as kcommon
-        from repro.kernels.pack8.ops import unpack8_sum_op
         sc = jnp.asarray(scale, jnp.float32).reshape((1,))
         parts = []
         for r0, nr in _ring_chunk_spans(payload.shape[0], self.ring_chunk_rows):
             chunk = jax.lax.slice_in_dim(payload, r0, r0 + nr, axis=0)
 
             def decode(b, s, _nr=nr):
-                return unpack8_sum_op(b[None], s, _nr * kcommon.LANES,
-                                      (_nr * kcommon.LANES,),
-                                      interpret=self._interpret())
+                return _unpack8_op()(b[None], s, _nr * kcommon.LANES,
+                                     (_nr * kcommon.LANES,),
+                                     interpret=self._interpret())
 
             parts.append(_ring_accumulate(chunk, (sc,), decode, self.axes,
                                           self.n_workers))
         flat = parts[0] if len(parts) == 1 else jnp.concatenate(parts)
         return jax.lax.slice(flat, (0,), (size,)).reshape(shape)
 
+    @scoped(EXCHANGE)
     def exchange_bucket(self, payload, bucket, *, scale=None):
         """ONE payload all-gather + ONE (n_slots,) scale-vector all-gather for
         the whole bucket. Slots are sublane-aligned (``bucketing``'s pack8
@@ -1152,7 +1179,6 @@ class Pack8Wire(VoteWire):
                 "the pack8 wire dequantizes during the exchange and needs "
                 "the bucket's per-slot decode scales (one f32 per leaf)")
         from repro.dist import bucketing  # lazy: bucketing imports this module
-        from repro.kernels.pack8.ops import unpack8_sum_op
         scale = jnp.asarray(scale, jnp.float32).reshape(-1)
         assert scale.shape[0] == len(bucket.slots), (scale.shape, bucket)
         if self.backend == "jnp":
@@ -1175,8 +1201,8 @@ class Pack8Wire(VoteWire):
         for i, s in enumerate(bucket.slots):
             rows = jax.lax.slice_in_dim(gathered, s.row_start,
                                         s.row_start + s.rows, axis=1)
-            out.append(unpack8_sum_op(rows, scales[:, i], s.size, s.shape,
-                                      interpret=interpret))
+            out.append(_unpack8_op()(rows, scales[:, i], s.size, s.shape,
+                                     interpret=interpret))
         return out
 
     def _ring_exchange_bucket(self, payload, scale, bucket):
@@ -1187,7 +1213,6 @@ class Pack8Wire(VoteWire):
         segment decoding through the unmodified fused kernel; per-slot
         segments re-concatenate in row order."""
         from repro.kernels import common as kcommon
-        from repro.kernels.pack8.ops import unpack8_sum_op
         outs = [[] for _ in bucket.slots]
         for r0, nr in _ring_chunk_spans(bucket.rows, self.ring_chunk_rows):
             chunk = jax.lax.slice_in_dim(payload, r0, r0 + nr, axis=0)
@@ -1198,7 +1223,7 @@ class Pack8Wire(VoteWire):
                 for i, _s, a, srows in _segs:
                     rows = jax.lax.slice_in_dim(b, a - _r0, a - _r0 + srows,
                                                 axis=0)
-                    res.append(unpack8_sum_op(
+                    res.append(_unpack8_op()(
                         rows[None], sc[i:i + 1], srows * kcommon.LANES,
                         (srows * kcommon.LANES,), interpret=self._interpret()))
                 return tuple(res)
@@ -1213,6 +1238,7 @@ class Pack8Wire(VoteWire):
             result.append(jax.lax.slice(flat, (0,), (s.size,)).reshape(s.shape))
         return result
 
+    @scoped(EXCHANGE)
     def exchange_weighted(self, values, size, shape, *, weight, scale=None):
         """Elastic pack8 exchange: the effective weight PREMULTIPLIES the
         decode scale (a dropped worker's scale*0 zeroes its dequantized
@@ -1266,6 +1292,7 @@ class Pack8Wire(VoteWire):
         flat = parts[0] if len(parts) == 1 else jnp.concatenate(parts)
         return jax.lax.slice(flat, (0,), (size,)).reshape(shape), wtot
 
+    @scoped(EXCHANGE)
     def exchange_bucket_weighted(self, payload, bucket, *, weight, scale=None):
         """Bucketed elastic pack8 exchange: the per-slot scale vector is
         premultiplied by the effective weight and widened by one raw-weight
@@ -1405,6 +1432,7 @@ class GolombWire(VoteWire):
     name = "allgather_golomb"
     native_format = "golomb"
 
+    @scoped(COUNTERS)
     def message_nnz(self, values):
         # the in-band header IS the count: bytes 0-3, uint32 little-endian
         # (shipped nonzeros — what the server's vote sum will see)
@@ -1417,6 +1445,7 @@ class GolombWire(VoteWire):
         h = values.reshape(-1)[4:8].astype(jnp.float32)
         return h[0] + h[1] * 256.0 + h[2] * 65536.0 + h[3] * 16777216.0
 
+    @scoped(EXCHANGE)
     def exchange(self, values, size, shape, *, scale=None):
         if scale is not None:
             raise ValueError(
@@ -1436,6 +1465,7 @@ class GolombWire(VoteWire):
                                    backend=self.backend)
         return total.astype(_sum_dtype(self.n_workers))
 
+    @scoped(EXCHANGE)
     def exchange_bucket(self, payload, bucket, *, scale=None):
         """ONE all-gather of the whole coded bucket, then per-slot fused
         decode-sums on the gathered row slices. Slots are whole capacity
@@ -1488,6 +1518,7 @@ class GolombWire(VoteWire):
                 out[slot_pos[s]] = arr.astype(_sum_dtype(self.n_workers))
         return out
 
+    @scoped(EXCHANGE)
     def exchange_weighted(self, values, size, shape, *, weight, scale=None):
         self._require_participation()
         if scale is not None:
@@ -1512,6 +1543,7 @@ class GolombWire(VoteWire):
                                  backend=self.backend)
         return wv, jnp.sum(wvec)
 
+    @scoped(EXCHANGE)
     def exchange_bucket_weighted(self, payload, bucket, *, weight, scale=None):
         self._require_participation()
         if scale is not None:

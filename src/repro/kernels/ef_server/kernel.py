@@ -41,4 +41,5 @@ def ef_server_2d(d2d, e2d, scale, *, block_rows: int, interpret: bool):
             jax.ShapeDtypeStruct((rows, lanes), jnp.float32),
         ),
         interpret=interpret,
+        name="ef_server_2d",
     )(jnp.asarray(scale, jnp.float32).reshape(1, 1), d2d, e2d)

@@ -79,6 +79,7 @@ def sparsign_golomb_2d(g2d: jnp.ndarray, seeds: jnp.ndarray, budget: jnp.ndarray
         out_shape=jax.ShapeDtypeStruct((out_rows, golomb_ref.ROW_BYTES),
                                        jnp.uint8),
         interpret=interpret,
+        name="sparsign_golomb_2d",
     )(seeds, budget, g2d)
 
 
@@ -101,6 +102,7 @@ def golomb_pack_2d(t2d: jnp.ndarray, *, b: int, out_rows: int, interpret: bool):
         out_shape=jax.ShapeDtypeStruct((out_rows, golomb_ref.ROW_BYTES),
                                        jnp.uint8),
         interpret=interpret,
+        name="golomb_pack_2d",
     )(t2d)
 
 
@@ -120,6 +122,7 @@ def ungolomb_sum(gathered: jnp.ndarray, *, n: int, b: int, interpret: bool):
         out_specs=pl.BlockSpec((n,), lambda i: (0,)),
         out_shape=jax.ShapeDtypeStruct((n,), jnp.int32),
         interpret=interpret,
+        name="ungolomb_sum",
     )(gathered)
 
 
@@ -146,4 +149,5 @@ def ungolomb_wsum(gathered: jnp.ndarray, w: jnp.ndarray, *, n: int, b: int,
         out_specs=pl.BlockSpec((n,), lambda i: (0,)),
         out_shape=jax.ShapeDtypeStruct((n,), jnp.float32),
         interpret=interpret,
+        name="ungolomb_wsum",
     )(w, gathered)

@@ -68,6 +68,7 @@ def pack2bit_2d(t2d: jnp.ndarray, *, block_rows: int, interpret: bool) -> jnp.nd
         out_specs=pl.BlockSpec((block_rows, q), lambda i: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((rows, q), jnp.uint8),
         interpret=interpret,
+        name="pack2bit_2d",
     )(t2d)
 
 
@@ -88,6 +89,7 @@ def unpack2bit_sum_2d(p3d: jnp.ndarray, *, block_rows: int, interpret: bool) -> 
         out_specs=pl.BlockSpec((block_rows, lanes), lambda i: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((rows, lanes), jnp.int32),
         interpret=interpret,
+        name="unpack2bit_sum_2d",
     )(p3d)
 
 
@@ -110,6 +112,7 @@ def unpack2bit_wsum_2d(p3d: jnp.ndarray, w: jnp.ndarray, *, block_rows: int,
         out_specs=pl.BlockSpec((block_rows, lanes), lambda i: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((rows, lanes), jnp.float32),
         interpret=interpret,
+        name="unpack2bit_wsum_2d",
     )(w, p3d)
 
 
@@ -124,4 +127,5 @@ def unpack2bit_2d(p2d: jnp.ndarray, *, block_rows: int, interpret: bool) -> jnp.
         out_specs=pl.BlockSpec((block_rows, lanes), lambda i: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((rows, lanes), jnp.int8),
         interpret=interpret,
+        name="unpack2bit_2d",
     )(p2d)

@@ -106,6 +106,7 @@ def qsgd8_pack8_2d(g2d: jnp.ndarray, seeds: jnp.ndarray, param: jnp.ndarray, *,
         out_specs=pl.BlockSpec((block_rows, lanes), lambda i: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((rows, lanes), jnp.int8),
         interpret=interpret,
+        name="qsgd8_pack8_2d",
     )(seeds, param, g2d)
 
 
@@ -129,4 +130,5 @@ def unpack8_sum_2d(p3d: jnp.ndarray, scales: jnp.ndarray, *,
         out_shape=jax.ShapeDtypeStruct((rows, lanes), jnp.float32),
         scratch_shapes=[pltpu.VMEM((m_chunk, block_rows, lanes), jnp.float32)],
         interpret=interpret,
+        name="unpack8_sum_2d",
     )(scales, p3d)

@@ -62,4 +62,5 @@ def sparsign_2d(g2d: jnp.ndarray, seeds: jnp.ndarray, budget: jnp.ndarray, *,
         out_specs=pl.BlockSpec((block_rows, lanes), lambda i: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((rows, lanes), jnp.int8),
         interpret=interpret,
+        name="sparsign_2d",
     )(seeds, budget, g2d)

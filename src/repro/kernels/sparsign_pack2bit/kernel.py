@@ -73,4 +73,5 @@ def sparsign_pack2bit_2d(g2d: jnp.ndarray, seeds: jnp.ndarray, budget: jnp.ndarr
         out_specs=pl.BlockSpec((block_rows, q), lambda i: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((rows, q), jnp.uint8),
         interpret=interpret,
+        name="sparsign_pack2bit_2d",
     )(seeds, budget, g2d)

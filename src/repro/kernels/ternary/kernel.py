@@ -91,6 +91,7 @@ def ternary_compress_2d(g2d: jnp.ndarray, scalars: jnp.ndarray, param: jnp.ndarr
         out_specs=pl.BlockSpec((block_rows, lanes), lambda i: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((rows, lanes), jnp.int8),
         interpret=interpret,
+        name="ternary_compress_2d",
     )(scalars, param, g2d)
 
 
@@ -113,4 +114,5 @@ def ternary_pack2bit_2d(g2d: jnp.ndarray, scalars: jnp.ndarray, param: jnp.ndarr
         out_specs=pl.BlockSpec((block_rows, q), lambda i: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((rows, q), jnp.uint8),
         interpret=interpret,
+        name="ternary_pack2bit_2d",
     )(scalars, param, g2d)

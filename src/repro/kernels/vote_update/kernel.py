@@ -42,6 +42,7 @@ def vote_update_2d(w2d, v2d, eta, quorum, *, block_rows: int, interpret: bool):
         out_specs=spec_w,
         out_shape=jax.ShapeDtypeStruct((rows, lanes), w2d.dtype),
         interpret=interpret,
+        name="vote_update_2d",
     )(eta, quorum, w2d, v2d)
 
 
@@ -72,4 +73,5 @@ def weighted_vote_update_2d(w2d, v2d, t2d, scalars, *, block_rows: int,
         out_specs=spec,
         out_shape=jax.ShapeDtypeStruct((rows, lanes), w2d.dtype),
         interpret=interpret,
+        name="weighted_vote_update_2d",
     )(scalars, w2d, v2d, t2d)

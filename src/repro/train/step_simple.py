@@ -41,6 +41,7 @@ from jax.sharding import PartitionSpec as P
 
 from repro.core import engine, prng
 from repro.core.algorithm import CompressionConfig
+from repro.core.scopes import COUNTERS, FWD_BWD, scoped
 from repro.dist import bucketing, collectives
 from repro.dist.sharding import ACT_RULES_TRAIN
 from repro.models.common import axis_rules
@@ -84,6 +85,7 @@ def _leaf_seeds(worker_seed, tree):
     return jax.tree_util.tree_unflatten(treedef, seeds)
 
 
+@scoped(FWD_BWD)
 def _local_grads(model, params, batch, comp_cfg: CompressionConfig, wseed, local_lr,
                  backend=None):
     """Returns (loss, message_source_tree).
@@ -199,12 +201,15 @@ def build_train_step(model, step_cfg: TrainStepConfig, mesh) -> Callable:
         new_params = jax.tree_util.tree_unflatten(treedef, new_leaves)
         new_ef_tree = (jax.tree_util.tree_unflatten(treedef, ef_leaves)
                        if state.ef_residual is not None else None)
-        loss_mean = collectives.scalar_psum(loss, axes) / n_workers
-        nnz_mean = collectives.scalar_psum(nnz_acc, axes) / n_workers / jnp.float32(total)
-        metrics = {"loss": loss_mean, "lr": lr, "nnz_frac": nnz_mean,
-                   "participated": collectives.scalar_psum(mask.astype(jnp.float32), axes),
-                   "wire_bytes_per_device": jnp.float32(wire_bytes),
-                   "gather_hbm_bytes": jnp.float32(gather_hbm)}
+        with jax.named_scope(COUNTERS):
+            loss_mean = collectives.scalar_psum(loss, axes) / n_workers
+            nnz_mean = (collectives.scalar_psum(nnz_acc, axes) / n_workers
+                        / jnp.float32(total))
+            metrics = {"loss": loss_mean, "lr": lr, "nnz_frac": nnz_mean,
+                       "participated": collectives.scalar_psum(
+                           mask.astype(jnp.float32), axes),
+                       "wire_bytes_per_device": jnp.float32(wire_bytes),
+                       "gather_hbm_bytes": jnp.float32(gather_hbm)}
         new_state = TrainState(params=new_params, ef_residual=new_ef_tree,
                                step=state.step + 1, seed=state.seed)
         return new_state, metrics
@@ -460,6 +465,10 @@ def build_train_step(model, step_cfg: TrainStepConfig, mesh) -> Callable:
         axis_names=engine.manual_axes(backend, mesh, axes),
         check_vma=False,
     )
+
+    def train_step(state, batch):
+        return wrapped(state, batch)
+
     if step_cfg.donate:
-        return jax.jit(wrapped, donate_argnums=(0,))
-    return jax.jit(wrapped)
+        return jax.jit(train_step, donate_argnums=(0,))
+    return jax.jit(train_step)

@@ -35,6 +35,7 @@ from jax.sharding import PartitionSpec as P
 
 from repro.core import engine, prng
 from repro.core.algorithm import CompressionConfig
+from repro.core.scopes import COUNTERS, FWD_BWD
 from repro.dist import bucketing, collectives
 from repro.dist.sharding import ACT_RULES_TRAIN
 from repro.models.common import axis_rules, rms_norm
@@ -532,22 +533,22 @@ def build_streamed_train_step(model, step_cfg: StreamedStepConfig, mesh) -> Call
             return jax.tree_util.tree_unflatten(treedef, out)
 
         # ---------------- forward ----------------
-        outer_full = {k: _gather(params[k], outer_axes[k]) for k in outer_keys}
-        h0 = model.embed_stage(outer_full if cfg.input_kind == "tokens" else params, batch)
-
         def fwd_body(h, block_shard):
             full = gather_block(block_shard)
             return model.superblock_apply(full, h, positions, positions3), h
-
-        h_final, h_inputs = jax.lax.scan(fwd_body, h0, params["blocks"])
 
         # ---------------- head / loss ----------------
         def head_fn(outer_p, h):
             hn = rms_norm(h, outer_p["final_norm"], cfg.norm_eps)
             return model.head_loss(outer_p, hn, batch["labels"])
 
-        loss, head_vjp = jax.vjp(head_fn, outer_full, h_final)
-        g_outer, g_h = head_vjp(jnp.float32(1.0))
+        with jax.named_scope(FWD_BWD):
+            outer_full = {k: _gather(params[k], outer_axes[k]) for k in outer_keys}
+            h0 = model.embed_stage(outer_full if cfg.input_kind == "tokens" else params,
+                                   batch)
+            h_final, h_inputs = jax.lax.scan(fwd_body, h0, params["blocks"])
+            loss, head_vjp = jax.vjp(head_fn, outer_full, h_final)
+            g_outer, g_h = head_vjp(jnp.float32(1.0))
 
         # ---------------- backward over superblocks ----------------
         if block_plan is not None:
@@ -586,13 +587,14 @@ def build_streamed_train_step(model, step_cfg: StreamedStepConfig, mesh) -> Call
                     block_plan, pbufs, psvecs, list(pps), list(pefs),
                     block_shard_axes, block_quorums, n_sel=n_sel_b, lr=lr,
                     w_eff=w_eff, w_psum=w_psum, q_fracs=block_q_fracs)
-                full = gather_block(block_shard)
 
                 def fwd(bp, h):
                     return model.superblock_apply(bp, h, positions, positions3)
 
-                _, vjp = jax.vjp(fwd, full, h_in)
-                g_block, g_h_prev = vjp(g_h)
+                with jax.named_scope(FWD_BWD):
+                    full = gather_block(block_shard)
+                    _, vjp = jax.vjp(fwd, full, h_in)
+                    g_block, g_h_prev = vjp(g_h)
                 g_leaves, g_def = jax.tree_util.tree_flatten(g_block)
                 ps_leaves = g_def.flatten_up_to(block_shard)
                 ef_leaves = (g_def.flatten_up_to(ef_slice) if has_ef
@@ -638,8 +640,9 @@ def build_streamed_train_step(model, step_cfg: StreamedStepConfig, mesh) -> Call
             if cfg.input_kind == "tokens":
                 def embed_fn(emb):
                     return model.embed_stage({"embed": emb}, batch)
-                _, embed_vjp = jax.vjp(embed_fn, outer_full["embed"])
-                (g_embed,) = embed_vjp(g_h0)
+                with jax.named_scope(FWD_BWD):
+                    _, embed_vjp = jax.vjp(embed_fn, outer_full["embed"])
+                    (g_embed,) = embed_vjp(g_h0)
 
             g_outer_leaves = []
             for k in outer_keys:
@@ -667,9 +670,10 @@ def build_streamed_train_step(model, step_cfg: StreamedStepConfig, mesh) -> Call
                 if has_ef:
                     new_ef[k] = ne
 
-            loss_mean = collectives.scalar_psum(loss, axes) / n_workers
-            nnz_mean = (collectives.scalar_psum(nnz_acc, axes) / n_workers
-                        / jnp.float32(total_coords))
+            with jax.named_scope(COUNTERS):
+                loss_mean = collectives.scalar_psum(loss, axes) / n_workers
+                nnz_mean = (collectives.scalar_psum(nnz_acc, axes) / n_workers
+                            / jnp.float32(total_coords))
             metrics = {"loss": loss_mean, "lr": lr, "nnz_frac": nnz_mean,
                        "participated": n_sel_b,
                        "wire_bytes_per_device": jnp.float32(wire_ledger),
@@ -684,13 +688,14 @@ def build_streamed_train_step(model, step_cfg: StreamedStepConfig, mesh) -> Call
                 block_shard, h_in, layer, ef_slice = xs
             else:
                 block_shard, h_in, layer = xs
-            full = gather_block(block_shard)
 
             def fwd(bp, h):
                 return model.superblock_apply(bp, h, positions, positions3)
 
-            _, vjp = jax.vjp(fwd, full, h_in)
-            g_block, g_h_prev = vjp(g_h)
+            with jax.named_scope(FWD_BWD):
+                full = gather_block(block_shard)
+                _, vjp = jax.vjp(fwd, full, h_in)
+                g_block, g_h_prev = vjp(g_h)
 
             g_leaves, g_def = jax.tree_util.tree_flatten(g_block)
             ps_leaves = g_def.flatten_up_to(block_shard)
@@ -729,8 +734,9 @@ def build_streamed_train_step(model, step_cfg: StreamedStepConfig, mesh) -> Call
         if cfg.input_kind == "tokens":
             def embed_fn(emb):
                 return model.embed_stage({"embed": emb}, batch)
-            _, embed_vjp = jax.vjp(embed_fn, outer_full["embed"])
-            (g_embed,) = embed_vjp(g_h0)
+            with jax.named_scope(FWD_BWD):
+                _, embed_vjp = jax.vjp(embed_fn, outer_full["embed"])
+                (g_embed,) = embed_vjp(g_h0)
 
         new_params = {"blocks": new_blocks}
         new_ef = {"blocks": new_ef_blocks} if has_ef else None
@@ -752,12 +758,15 @@ def build_streamed_train_step(model, step_cfg: StreamedStepConfig, mesh) -> Call
             if has_ef:
                 new_ef[k] = new_ef_k
 
-        loss_mean = collectives.scalar_psum(loss, axes) / n_workers
-        nnz_mean = collectives.scalar_psum(nnz_acc, axes) / n_workers / jnp.float32(total_coords)
-        metrics = {"loss": loss_mean, "lr": lr, "nnz_frac": nnz_mean,
-                   "participated": collectives.scalar_psum(mask.astype(jnp.float32), axes),
-                   "wire_bytes_per_device": jnp.float32(wire_ledger),
-                   "gather_hbm_bytes": jnp.float32(gather_hbm)}
+        with jax.named_scope(COUNTERS):
+            loss_mean = collectives.scalar_psum(loss, axes) / n_workers
+            nnz_mean = (collectives.scalar_psum(nnz_acc, axes) / n_workers
+                        / jnp.float32(total_coords))
+            metrics = {"loss": loss_mean, "lr": lr, "nnz_frac": nnz_mean,
+                       "participated": collectives.scalar_psum(
+                           mask.astype(jnp.float32), axes),
+                       "wire_bytes_per_device": jnp.float32(wire_ledger),
+                       "gather_hbm_bytes": jnp.float32(gather_hbm)}
         new_state = TrainState(params=new_params, ef_residual=new_ef,
                                step=state.step + 1, seed=state.seed)
         return new_state, metrics
@@ -781,9 +790,13 @@ def build_streamed_train_step(model, step_cfg: StreamedStepConfig, mesh) -> Call
         axis_names=engine.manual_axes(backend, mesh, set(axes) | {fsdp_ax}),
         check_vma=False,
     )
+
+    def train_step(state, batch):
+        return wrapped(state, batch)
+
     if step_cfg.donate:
-        return jax.jit(wrapped, donate_argnums=(0,))
-    return jax.jit(wrapped)
+        return jax.jit(train_step, donate_argnums=(0,))
+    return jax.jit(train_step)
 
 
 def fsdp_param_shardings(model, mesh, fsdp_axis: str = "data"):

@@ -12,8 +12,13 @@ The topology is described inside a module fixture, never at import: only one
 process may load the TPU library, and it keeps it until it exits.
 """
 
+import importlib.util
+import json
 import math
 import os
+import pathlib
+import re
+import sys
 
 import jax
 import jax.numpy as jnp
@@ -118,32 +123,153 @@ def test_kernel_compiles_for_v5e(name, one_chip):
     assert "tpu_custom_call" in compiled.as_text()
 
 
+def _simple_step(mesh, backend, *, server="majority_vote", vote_impl="allgather_packed"):
+    """(step, state shapes, batch shapes) of the simple trainer on the smoke
+    mamba2-370m, the batch split over the data axis."""
+    from repro.configs.registry import get_config
+    from repro.core.algorithm import CompressionConfig
+    from repro.models.model import Model
+    from repro.train.state import LrSchedule, init_state
+    from repro.train.step_simple import TrainStepConfig, build_train_step
+
+    model = Model(get_config("mamba2-370m", smoke=True))
+    comp = CompressionConfig(compressor="sparsign", server=server)
+    step = build_train_step(model, TrainStepConfig(
+        compression=comp, lr=LrSchedule(base=1e-3), vote_impl=vote_impl,
+        backend=backend), mesh)
+    state = jax.eval_shape(lambda: init_state(model.init(jax.random.PRNGKey(0)),
+                                              server=server, seed=0))
+    rep = NamedSharding(mesh, P())
+    state = jax.tree_util.tree_map(
+        lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=rep), state)
+    rows = 8 * mesh.shape["data"]
+    batch = {k: jax.ShapeDtypeStruct((rows, 64), jnp.int32,
+                                     sharding=NamedSharding(mesh, P("data")))
+             for k in ("inputs", "labels", "positions")}
+    return step, state, batch
+
+
 @pytest.mark.parametrize("server,vote_impl", [("scaled_sign_ef", "psum"),
                                               ("majority_vote", "allgather_packed")])
 def test_train_step_compiles_for_v5e(server, vote_impl, topo):
     """The simple trainer's whole step with the pallas backend on a (1, 1)
     mesh: the kernels sit inside the step's shard_map, where an axis left to
     GSPMD would make Mosaic refuse them."""
-    from repro.configs.registry import get_config
-    from repro.core.algorithm import CompressionConfig
     from repro.launch.mesh import make_mesh
-    from repro.models.model import Model
-    from repro.train.state import LrSchedule, init_state
-    from repro.train.step_simple import TrainStepConfig, build_train_step
 
     mesh = make_mesh((1, 1), ("data", "model"), devices=topo.devices[:1])
-    model = Model(get_config("mamba2-370m", smoke=True))
-    comp = CompressionConfig(compressor="sparsign", server=server)
-    step = build_train_step(model, TrainStepConfig(
-        compression=comp, lr=LrSchedule(base=1e-3), vote_impl=vote_impl,
-        backend="pallas"), mesh)
-    state = jax.eval_shape(lambda: init_state(model.init(jax.random.PRNGKey(0)),
-                                              server=server, seed=0))
-    rep = NamedSharding(mesh, P())
-    state = jax.tree_util.tree_map(
-        lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=rep), state)
-    batch = {k: jax.ShapeDtypeStruct((8, 64), jnp.int32, sharding=rep)
-             for k in ("inputs", "labels", "positions")}
+    step, state, batch = _simple_step(mesh, "pallas", server=server, vote_impl=vote_impl)
     with jax.sharding.set_mesh(mesh):
         compiled = step.lower(state, batch).compile()
     assert "tpu_custom_call" in compiled.as_text()
+
+
+# ---------------------------------------------------------------------------
+# The layer scopes the benchmark reads its per-layer device time by
+# ---------------------------------------------------------------------------
+
+ROOT = pathlib.Path(__file__).resolve().parents[1]
+
+
+def _bench_scopes():
+    """``bench/scopes.py``: the rule that gives an operation its layer."""
+    spec = importlib.util.spec_from_file_location("bench_scopes",
+                                                  ROOT / "bench" / "scopes.py")
+    mod = importlib.util.module_from_spec(spec)
+    sys.modules[spec.name] = mod
+    spec.loader.exec_module(mod)
+    return mod
+
+
+def _kernel_patterns():
+    """{layer key: compiled name patterns} of ``bench/layers/*.json``."""
+    out = {}
+    for f in sorted((ROOT / "bench" / "layers").glob("*.json")):
+        out[f.stem] = [re.compile(p) for p in json.loads(f.read_text()).get("patterns", [])]
+    return out
+
+
+def _instructions(hlo_text):
+    """(name, opcode, op_name) of every instruction of a compiled HLO text."""
+    out = []
+    for line in hlo_text.splitlines():
+        m = re.match(r"\s*(?:ROOT )?(%\S+) = \S+ ([\w-]+)\(", line)
+        if m:
+            meta = re.search(r'op_name="([^"]*)"', line)
+            out.append((m.group(1), m.group(2), meta.group(1) if meta else ""))
+    return out
+
+
+@pytest.mark.parametrize("workers", [1, 4])
+def test_train_step_scopes_on_v5e(workers, topo):
+    """Compiled for a v5e, every kernel and collective of the simple
+    trainer's step sits under its layer's scope, and the kernels keep the
+    instruction names the benchmark's name patterns find."""
+    from repro.launch.mesh import make_mesh
+
+    layer_of = _bench_scopes().layer_of
+    mesh = make_mesh((workers, 1), ("data", "model"), devices=topo.devices[:workers])
+    step, state, batch = _simple_step(mesh, "pallas")
+    with jax.sharding.set_mesh(mesh):
+        ins = _instructions(step.lower(state, batch).compile().as_text())
+    patterns = _kernel_patterns()
+    seen = {"uplink": 0, "server": 0, "exchange": 0, "counters": 0}
+    for name, opcode, op_name in ins:
+        layer = layer_of(op_name)
+        if opcode == "custom-call" and "pallas_call" in op_name:
+            want = "uplink" if name.startswith("%sparsign") else "server"
+            assert name.startswith(("%sparsign", "%unpack2bit", "%vote_update")), name
+            assert layer == want, (name, op_name)
+            assert any(p.search(name) for p in patterns[want]), name
+            seen[want] += 1
+        elif opcode in ("all-gather", "all-gather-start"):
+            assert layer == "exchange", (name, op_name)
+            seen["exchange"] += 1
+        elif "reduce" in name and layer == "counters":
+            seen["counters"] += 1
+    leaves = 16   # the smoke mamba2's parameter leaves
+    assert seen["uplink"] == leaves and seen["server"] == 2 * leaves
+    assert seen["exchange"] == (leaves if workers > 1 else 0)
+    assert seen["counters"] >= 1   # the nonzero count over the payloads
+
+
+def test_innermost_scope_rule():
+    scopes = _bench_scopes()
+    cases = {
+        "jit(train_step)/shard_map/fwd_bwd/transpose(jvp())/while/body/closed_call/"
+        "checkpoint/rematted_computation/dot_general": "fwd_bwd",
+        "transpose(jvp(fwd_bwd))/transpose(jvp(mixer))/mul": "fwd_bwd",
+        "jit(train_step)/exchange/server/jit(unpack2bit_sum_op)/pallas_call": "server",
+        "jit(train_step)/shard_map/exchange/all_gather": "exchange",
+        "jit(train_step)/uplink/uplink/jit(sparsign_pack2bit_op)/scatter": "uplink",
+        "jit(train_step)/counters/psum": "counters",
+        "jit(train_step)/shard_map/xor": "unscoped",
+        "jit(server_fn)/fwd_bwd_extra/mul": "unscoped",
+        "": "unscoped",
+    }
+    for op_name, want in cases.items():
+        assert scopes.layer_of(op_name) == want, op_name
+
+
+def test_step_scopes_on_cpu():
+    """Compiled on the CPU with the jnp backend, the step's operations take
+    their layers by the innermost rule: the backward (``transpose(jvp())``)
+    and the rematerialised forward (``checkpoint``) fall to ``fwd_bwd``, the
+    decode-sum called inside the exchange to ``server``."""
+    from repro.launch.mesh import make_mesh
+
+    layer_of = _bench_scopes().layer_of
+    mesh = make_mesh((1, 1), ("data", "model"), devices=jax.devices()[:1])
+    step, state, batch = _simple_step(mesh, "jnp")
+    with jax.sharding.set_mesh(mesh):
+        names = {n for _, _, n in _instructions(step.lower(state, batch).compile().as_text())}
+    layers = {}
+    for n in names:
+        layers.setdefault(layer_of(n), []).append(n)
+    assert set(layers) >= {"fwd_bwd", "uplink", "exchange", "server", "counters"}
+    backward = [n for n in names if "transpose(jvp(" in n]
+    remat = [n for n in names if "/checkpoint/" in n]
+    assert backward and remat
+    assert {layer_of(n) for n in backward + remat} == {"fwd_bwd"}
+    decode = [n for n in names if "/exchange/server/" in n]
+    assert decode and {layer_of(n) for n in decode} == {"server"}
