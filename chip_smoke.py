@@ -12,9 +12,9 @@ One chip, in order:
 
 1. the device JAX reports (platform, kind, count);
 2. kernel parity: the compiled Pallas kernels of the training path
-   (sparsign, sparsign_pack2bit, unpack2bit_sum, vote_update, ef_server) on
-   the 50280x1024 mamba2-370m embedding leaf, bitwise against their jnp
-   references;
+   (sparsign, sparsign_pack2bit, unpack2bit_sum, vote_update,
+   vote_update_packed2bit, ef_server) on the 50280x1024 mamba2-370m embedding
+   leaf, bitwise against their jnp references;
 3. train steps: mamba2-370m at its published widths and full depth, through
    ``repro.launch.train.build_everything`` and ``repro.train.loop.run`` with
    the pallas backend, batch 8 x 2048 tokens: 3 steps with the CLI defaults
@@ -46,7 +46,8 @@ ROOT = pathlib.Path(__file__).resolve().parent
 ARCH = "mamba2-370m"
 BATCH, SEQ_LEN = 8, 2048
 STEPS = 3
-KERNEL_WORKERS = 4   # gathered payloads in the unpack2bit_sum parity case
+KERNEL_WORKERS = 4   # gathered payloads in the unpack2bit_sum and
+                     # vote_update_packed2bit parity cases
 BACKEND_COMPILE_EVENT = "/jax/core/compile/backend_compile_duration"
 
 
@@ -131,8 +132,10 @@ def kernel_parity(cfg) -> None:
     from repro.kernels.sparsign.ref import sparsign_ref
     from repro.kernels.sparsign_pack2bit.ops import sparsign_pack2bit_op
     from repro.kernels.sparsign_pack2bit.ref import sparsign_pack2bit_ref
-    from repro.kernels.vote_update.ops import vote_update_op
-    from repro.kernels.vote_update.ref import vote_update_ref
+    from repro.kernels.vote_update.ops import (vote_update_op,
+                                               vote_update_packed2bit_op)
+    from repro.kernels.vote_update.ref import (vote_update_packed2bit_ref,
+                                               vote_update_ref)
 
     shape = (cfg.vocab_size, cfg.d_model)
     n = math.prod(shape)
@@ -163,11 +166,16 @@ def kernel_parity(cfg) -> None:
         "vote_update": (
             lambda *a: vote_update_op(*a, quorum=2, interpret=False),
             lambda *a: vote_update_ref(*a, quorum=2), (w, votes, eta)),
+        "vote_update_packed2bit": (
+            lambda *a: vote_update_packed2bit_op(*a, quorum=2, interpret=False),
+            lambda *a: vote_update_packed2bit_ref(*a, quorum=2),
+            (w, gathered, eta)),
         "ef_server": (lambda *a: ef_server_op(*a, interpret=False), ef_server_ref,
                       (d, e, scale)),
     }
     print(f"[kernels] leaf {shape} {jnp.dtype(dt).name}, "
-          f"{KERNEL_WORKERS}-worker gather for unpack2bit_sum", flush=True)
+          f"{KERNEL_WORKERS}-worker gather for unpack2bit_sum and "
+          f"vote_update_packed2bit", flush=True)
     for name, (kernel, ref, operands) in cases.items():
         kernel = jax.jit(kernel)
         if "tpu_custom_call" not in kernel.lower(*operands).as_text():
@@ -181,8 +189,8 @@ def kernel_parity(cfg) -> None:
         if bad != 0:
             fail(f"kernel {name} differs from its jnp reference "
                  f"({bad} elements, -1 = shape/dtype)")
-    print("[kernels] parity passed: 5 kernels bitwise equal to their references",
-          flush=True)
+    print(f"[kernels] parity passed: {len(cases)} kernels bitwise equal to "
+          f"their references", flush=True)
 
 
 def train_argv(*extra: str) -> list:

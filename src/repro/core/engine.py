@@ -24,10 +24,12 @@ which scale protocol, which server decode — are answered by the declarative
 ``CompressorSpec`` table (``repro.core.compressors.SPECS``); this module has
 no compressor-name special cases.
 
-Two primitives:
+Three primitives:
 
   compress_leaf(g, cfg, seed, counter_base)        — worker uplink Q(g, B)
   server_apply(p, vote_sum, cfg, ...)              — C(.) [+ EF] + SGD update
+  server_apply_packed(p, gathered, cfg, ...)       — majority vote + SGD from
+                                                     the gathered 2-bit payloads
 
 plus the small shared helpers (vote-server predicates, wire-mode negotiation,
 per-leaf quorum broadcasting, local-step config) that keep server-rule and
@@ -55,8 +57,10 @@ from repro.kernels.golomb.ref import golomb_encode_ref
 from repro.kernels.pack2bit.ops import pack2bit_op
 from repro.kernels.pack2bit.ref import pack2bit_ref
 from repro.kernels.vote_update.ops import (vote_update_op,
+                                           vote_update_packed2bit_op,
                                            weighted_vote_update_op)
-from repro.kernels.vote_update.ref import (vote_update_ref,
+from repro.kernels.vote_update.ref import (vote_update_packed2bit_ref,
+                                           vote_update_ref,
                                            weighted_vote_update_ref)
 
 if TYPE_CHECKING:  # avoid a runtime cycle: algorithm imports this module
@@ -535,3 +539,33 @@ def server_apply(
         return (p.astype(jnp.float32) - lr * upd).astype(p.dtype), new_ef
 
     raise ValueError(f"unknown server rule {rule!r}; known: {SERVER_RULES}")
+
+
+@scoped(SERVER)
+def server_apply_packed(
+    p: jnp.ndarray,
+    gathered: jnp.ndarray,
+    cfg: "CompressionConfig",
+    *,
+    lr,
+    quorum: int = 1,
+    backend: Optional[str] = None,
+) -> jnp.ndarray:
+    """Majority vote + SGD for one leaf, straight from the all-gather wire's
+    (M, rows, LANES//4) 2-bit payloads (``PackedVoteWire.gather``).
+
+    The pallas/interpret backends take the fused ``vote_update_packed2bit``
+    kernel: the payloads are decoded and summed in VMEM, so no vote sum
+    reaches HBM. The jnp backend runs the unfused reference chain (decode-sum,
+    then ``vote_update_ref``). Both are bitwise ``server_apply`` of the
+    wire's ``exchange``. Returns ``new_p`` in ``p.dtype``."""
+    backend = resolve_backend(backend)
+    if cfg.server != "majority_vote":
+        raise ValueError(
+            f"the fused packed update is the integer majority vote; server "
+            f"{cfg.server!r} needs the decoded vote sum (server_apply)")
+    lr = jnp.asarray(lr, jnp.float32)
+    if backend == "jnp":
+        return vote_update_packed2bit_ref(p, gathered, lr, quorum=quorum)
+    return vote_update_packed2bit_op(p, gathered, lr, quorum=quorum,
+                                     interpret=(backend == "interpret"))

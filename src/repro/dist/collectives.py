@@ -684,6 +684,14 @@ class VoteWire:
         """Does this wire speak a packed canonical view (vs leaf-shaped votes)?"""
         return self.native_format != "int8"
 
+    @property
+    def gathers_packed(self) -> bool:
+        """Is the exchange one all-gather of whole 2-bit payloads (``gather``),
+        so the server can decode them inside its update? False for the psum
+        wires, the other payload formats and the chunked ring, which
+        accumulates the decoded sum hop by hop."""
+        return False
+
     @scoped(UPLINK)
     def mask_message(self, values: jnp.ndarray, mask) -> jnp.ndarray:
         """Zero a non-participating worker's message, in wire-native format
@@ -963,6 +971,17 @@ class PackedVoteWire(VoteWire):
                                           self.n_workers))
         return parts[0] if len(parts) == 1 else jnp.concatenate(parts)
 
+    @property
+    def gathers_packed(self) -> bool:
+        return self.ring_chunk_rows is None
+
+    @scoped(EXCHANGE)
+    def gather(self, values) -> jnp.ndarray:
+        """The exchange without its decode: every worker's (rows, LANES//4)
+        packed message, all-gathered to (M, rows, LANES//4) — what
+        ``engine.server_apply_packed`` decodes inside the update."""
+        return jax.lax.all_gather(values, self.axes, axis=0, tiled=False)
+
     @scoped(EXCHANGE)
     def exchange(self, values, size, shape, *, scale=None):
         if scale is not None:
@@ -973,7 +992,7 @@ class PackedVoteWire(VoteWire):
             flat = self._ring_decode_flat(values)
             total = jax.lax.slice(flat, (0,), (size,)).reshape(shape)
             return total.astype(_sum_dtype(self.n_workers))
-        gathered = jax.lax.all_gather(values, self.axes, axis=0, tiled=False)
+        gathered = self.gather(values)
         total = _packed_decode_sum(gathered, size, shape, backend=self.backend)
         return total.astype(_sum_dtype(self.n_workers))
 

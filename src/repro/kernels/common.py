@@ -116,6 +116,15 @@ def block_rows_for(rows: int, want: int = DEFAULT_BLOCK_ROWS) -> int:
     return max(want, SUBLANE_PAD)
 
 
+def gather_block_rows(gathered_shape) -> int:
+    """Block rows of a kernel that reads an (M, rows, q) gathered payload:
+    the (M, block, q) input block stays within about 2 MiB of VMEM at any
+    worker count, so the block shrinks as M grows."""
+    m, rows, q = gathered_shape
+    want = max(SUBLANE_PAD, min(DEFAULT_BLOCK_ROWS, (1 << 21) // max(1, m * q)))
+    return block_rows_for(rows, want=want)
+
+
 def smem_row(dtype, *xs) -> jnp.ndarray:
     """Scalars ride in SMEM as one (1, k) row per dtype. Float scalars get a
     float32 row of their own: Mosaic cannot bitcast an SMEM scalar."""

@@ -48,9 +48,7 @@ def unpack2bit_sum_op(gathered: jnp.ndarray, n: int, shape, *,
     """
     if interpret is None:
         interpret = common.default_interpret()
-    m, rows, q = gathered.shape
-    want = max(common.SUBLANE_PAD, min(common.DEFAULT_BLOCK_ROWS, (1 << 21) // max(1, m * q)))
-    br = common.block_rows_for(rows, want=want)
+    br = common.gather_block_rows(gathered.shape)
     total2d = unpack2bit_sum_2d(gathered, block_rows=br, interpret=interpret)
     return common.from_2d(total2d, n, shape)
 
@@ -68,9 +66,7 @@ def unpack2bit_wsum_op(gathered: jnp.ndarray, weights: jnp.ndarray, n: int,
     """
     if interpret is None:
         interpret = common.default_interpret()
-    m, rows, q = gathered.shape
-    want = max(common.SUBLANE_PAD, min(common.DEFAULT_BLOCK_ROWS, (1 << 21) // max(1, m * q)))
-    br = common.block_rows_for(rows, want=want)
-    w = weights.astype(jnp.float32).reshape(1, m)
+    br = common.gather_block_rows(gathered.shape)
+    w = weights.astype(jnp.float32).reshape(1, gathered.shape[0])
     total2d = unpack2bit_wsum_2d(gathered, w, block_rows=br, interpret=interpret)
     return common.from_2d(total2d, n, shape)

@@ -8,7 +8,9 @@ import jax
 import jax.numpy as jnp
 
 from repro.kernels import common
-from repro.kernels.vote_update.kernel import vote_update_2d, weighted_vote_update_2d
+from repro.kernels.vote_update.kernel import (vote_update_2d,
+                                              vote_update_packed2bit_2d,
+                                              weighted_vote_update_2d)
 
 
 @functools.partial(jax.jit, static_argnames=("quorum", "interpret"))
@@ -23,6 +25,30 @@ def vote_update_op(w: jnp.ndarray, votes: jnp.ndarray, eta, *, quorum: int = 1,
     out2 = vote_update_2d(w2, v2, common.smem_row(jnp.float32, eta),
                           common.smem_row(jnp.int32, quorum),
                           block_rows=br, interpret=interpret)
+    return common.from_2d(out2, n, w.shape)
+
+
+@functools.partial(jax.jit, static_argnames=("quorum", "interpret"))
+def vote_update_packed2bit_op(w: jnp.ndarray, gathered: jnp.ndarray, eta, *,
+                              quorum: int = 1,
+                              interpret: bool | None = None) -> jnp.ndarray:
+    """w' = w - eta * sign(votes) with quorum deadband, where votes is the sum
+    of the (M, rows, LANES//4) gathered 2-bit payloads over the canonical view
+    of ``w``; any shape, w dtype preserved. Bitwise ``vote_update_op`` of
+    ``unpack2bit_sum_op``'s vote sum, with no vote sum in HBM. Block rows
+    follow the gathered decode-sums' VMEM rule."""
+    if interpret is None:
+        interpret = common.default_interpret()
+    w2, n = common.to_2d(w.reshape(-1))
+    if gathered.shape[1:] != (w2.shape[0], common.LANES // 4):
+        raise ValueError(
+            f"gathered payloads {gathered.shape} do not pack the canonical "
+            f"view {w2.shape} of a {w.shape} parameter")
+    out2 = vote_update_packed2bit_2d(
+        w2, gathered, common.smem_row(jnp.float32, eta),
+        common.smem_row(jnp.int32, quorum),
+        block_rows=common.gather_block_rows(gathered.shape),
+        interpret=interpret)
     return common.from_2d(out2, n, w.shape)
 
 

@@ -9,11 +9,24 @@ from __future__ import annotations
 
 import jax.numpy as jnp
 
+from repro.kernels import common
+from repro.kernels.pack2bit.ref import unpack2bit_sum_ref
+
 
 def vote_update_ref(w: jnp.ndarray, votes: jnp.ndarray, eta, quorum: int = 1) -> jnp.ndarray:
     v = votes.astype(jnp.int32)
     step = jnp.where(jnp.abs(v) >= quorum, jnp.sign(v), 0).astype(jnp.float32)
     return (w.astype(jnp.float32) - jnp.float32(eta) * step).astype(w.dtype)
+
+
+def vote_update_packed2bit_ref(w: jnp.ndarray, gathered: jnp.ndarray, eta,
+                               quorum: int = 1) -> jnp.ndarray:
+    """Oracle for the fused decode + update: the unfused chain, which decodes
+    the (M, rows, L//4) gathered 2-bit payloads into an int32 vote sum in the
+    shape of ``w`` and applies ``vote_update_ref``. (The wire's narrowing of
+    the sum to int8 or int16 holds every value in [-M, M], so it is left out.)"""
+    votes = common.from_2d(unpack2bit_sum_ref(gathered), w.size, w.shape)
+    return vote_update_ref(w, votes, eta, quorum)
 
 
 def weighted_vote_update_ref(w: jnp.ndarray, wvotes: jnp.ndarray,

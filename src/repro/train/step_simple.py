@@ -15,7 +15,9 @@ Per round (Algorithm 1 / Algorithm 2 with tau=1..):
      (`repro.dist.collectives.VoteWire`: psum | hier | allgather_packed),
   4. C(.) (majority vote sign, scaled-sign with server-side EF, or the scaled
      mean for shared-scale ternary baselines) computed redundantly everywhere
-     = free downlink,
+     = free downlink; over the monolithic 2-bit gather the majority vote
+     decodes the gathered payloads inside its update kernel
+     (``engine.server_apply_packed``), and no vote sum reaches HBM,
   5. SGD update; params stay bitwise identical across workers.
 
 Which wire a compressor rides is negotiated from the CompressorSpec table
@@ -166,6 +168,12 @@ def build_train_step(model, step_cfg: TrainStepConfig, mesh) -> Callable:
     # bad quorums and q_frac out of (0,1] fail before tracing)
     q_fracs = ([part.resolve_q_frac(q, wire.n_workers) for q in quorum_leaves]
                if part is not None else None)
+    # the per-leaf majority vote over the monolithic 2-bit gather decodes
+    # the payloads inside the update kernel (engine.server_apply_packed); the
+    # ring, bucketed and elastic paths need the decoded vote sum itself
+    fused_server = (mode == "votes" and comp.server == "majority_vote"
+                    and wire.gathers_packed and part is None
+                    and not step_cfg.bucketed)
     if mode != "votes" and any(q != 1 for q in quorum_leaves):
         raise ValueError(
             f"quorum={step_cfg.quorum!r} is a vote-server deadband, but "
@@ -196,7 +204,7 @@ def build_train_step(model, step_cfg: TrainStepConfig, mesh) -> Callable:
             return _body_inner(state, batch)
 
     def _finish(state, treedef, new_leaves, ef_leaves, loss, lr, nnz_acc,
-                total, mask, wire_bytes, gather_hbm):
+                total, mask, wire_bytes, gather_hbm, fused=0):
         n_workers = collectives.worker_count(axes)
         new_params = jax.tree_util.tree_unflatten(treedef, new_leaves)
         new_ef_tree = (jax.tree_util.tree_unflatten(treedef, ef_leaves)
@@ -209,7 +217,10 @@ def build_train_step(model, step_cfg: TrainStepConfig, mesh) -> Callable:
                        "participated": collectives.scalar_psum(
                            mask.astype(jnp.float32), axes),
                        "wire_bytes_per_device": jnp.float32(wire_bytes),
-                       "gather_hbm_bytes": jnp.float32(gather_hbm)}
+                       "gather_hbm_bytes": jnp.float32(gather_hbm),
+                       # share of the coordinates updated by the fused
+                       # decode + update kernel (static under jit)
+                       "server_fused_share": jnp.float32(fused / total)}
         new_state = TrainState(params=new_params, ef_residual=new_ef_tree,
                                step=state.step + 1, seed=state.seed)
         return new_state, metrics
@@ -241,6 +252,7 @@ def build_train_step(model, step_cfg: TrainStepConfig, mesh) -> Callable:
         lr = step_cfg.lr(state.step)
         nnz_acc = jnp.float32(0.0)
         total = 0
+        fused = 0          # coordinates updated by the fused server kernel
         wire_bytes = 0.0   # per-device uplink ledger (static sizes under jit)
         gather_hbm = 0.0   # peak gather-payload residency (max over exchanges)
 
@@ -406,6 +418,12 @@ def build_train_step(model, step_cfg: TrainStepConfig, mesh) -> Callable:
                     new_p, new_ef = engine.server_apply(
                         p, dec_sum, comp, lr=lr, ef=ef, n_sel=n_sel,
                         server="mean", backend=backend)
+                elif fused_server:
+                    new_p = engine.server_apply_packed(
+                        p, wire.gather(votes), comp, lr=lr,
+                        quorum=quorum_leaves[i], backend=backend)
+                    new_ef = ef
+                    fused += g.size
                 elif mode == "votes":
                     vote_sum = wire.exchange(votes, g.size, g.shape)
                     new_p, new_ef = engine.server_apply(
@@ -448,7 +466,7 @@ def build_train_step(model, step_cfg: TrainStepConfig, mesh) -> Callable:
             ef_leaves.append(new_ef)
 
         return _finish(state, treedef, new_leaves, ef_leaves, loss, lr,
-                       nnz_acc, total, mask, wire_bytes, gather_hbm)
+                       nnz_acc, total, mask, wire_bytes, gather_hbm, fused)
 
     state_spec = P()   # replicated w.r.t. the manual worker axes
     batch_axis = 1 if comp.local_steps > 1 else 0

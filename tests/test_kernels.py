@@ -9,15 +9,15 @@ from hypothesis import given, settings, strategies as st
 from repro.kernels import common
 from repro.kernels.ef_server.ops import ef_server_op
 from repro.kernels.ef_server.ref import ef_scale, ef_server_ref
-from repro.kernels.pack2bit.ops import pack2bit_op, unpack2bit_op
+from repro.kernels.pack2bit.ops import pack2bit_op, unpack2bit_op, unpack2bit_sum_op
 from repro.kernels.pack2bit.ref import pack2bit_ref, unpack2bit_ref
 from repro.kernels.sparsign.ops import sparsign_op
 from repro.kernels.sparsign.ref import sparsign_ref
 from repro.kernels.ternary.ops import ternary_compress_op, ternary_pack2bit_op
 from repro.kernels.ternary.ref import ternary_compress_ref, ternary_pack2bit_ref
 from repro.kernels.ternary.rules import RULES
-from repro.kernels.vote_update.ops import vote_update_op
-from repro.kernels.vote_update.ref import vote_update_ref
+from repro.kernels.vote_update.ops import vote_update_op, vote_update_packed2bit_op
+from repro.kernels.vote_update.ref import vote_update_packed2bit_ref, vote_update_ref
 
 SHAPES = [(64,), (1000,), (7, 333), (2, 3, 129), (513, 511), (1 << 16,)]
 DTYPES = ["float32", "bfloat16"]
@@ -143,3 +143,39 @@ def test_vote_update_semantics():
     v = jnp.asarray([3, -2, 0, 1, -1, 5, -5, 0], jnp.int32)
     out = np.asarray(vote_update_op(w, v, 1.0))
     assert np.array_equal(out, -np.sign(np.asarray(v)).astype(np.float32))
+
+
+# (350, 400): 140,000 coordinates, 274 rows padded to 288, which the block
+# rule splits into three blocks of 96; its last worker's payload is zeroed,
+# as the wire masks a non-participating worker
+@pytest.mark.parametrize("shape,masked", [((777,), False), ((350, 400), True)])
+@pytest.mark.parametrize("dtype", DTYPES)
+@pytest.mark.parametrize("quorum", [1, 3])
+@pytest.mark.parametrize("m", [1, 4, 5])
+def test_vote_update_packed2bit_matches_chain(m, quorum, dtype, shape, masked):
+    """The fused decode + update is bitwise the chain it replaces: decode-sum
+    to int32, narrow to int8, vote update."""
+    rng = np.random.RandomState(10 * m + quorum)
+    n = int(np.prod(shape))
+    gathered = jnp.stack([pack2bit_op(jnp.asarray(rng.randint(-1, 2, shape), jnp.int8))
+                          for _ in range(m)])
+    if masked:
+        assert (gathered.shape[1], common.gather_block_rows(gathered.shape)) == (288, 96)
+        if m > 1:
+            gathered = gathered.at[-1].set(0)
+    w = jnp.asarray(rng.randn(*shape), dtype)
+    got = vote_update_packed2bit_op(w, gathered, 0.05, quorum=quorum)
+    chain = vote_update_op(w, unpack2bit_sum_op(gathered, n, shape).astype(jnp.int8),
+                           0.05, quorum=quorum)
+    assert got.dtype == w.dtype and got.shape == w.shape
+    assert np.array_equal(np.asarray(got), np.asarray(chain))
+    assert np.array_equal(np.asarray(got),
+                          np.asarray(vote_update_packed2bit_ref(w, gathered, 0.05, quorum)))
+    moved = np.asarray(got) != np.asarray(w)
+    assert moved.any() == (m >= quorum) and not moved.all()
+
+
+def test_vote_update_packed2bit_rejects_foreign_payload():
+    w = jnp.zeros((777,), jnp.float32)
+    with pytest.raises(ValueError, match="canonical view"):
+        vote_update_packed2bit_op(w, jnp.zeros((2, 64, 128), jnp.uint8), 0.1)

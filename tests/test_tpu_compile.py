@@ -4,9 +4,10 @@ Interpret mode cannot show what Mosaic refuses (casts, SMEM bitcasts, 8-bit
 compares, relayouts, auto-partitioned kernels); compiling with
 ``interpret=False`` for a described ``v5e:2x2`` topology does. Every kernel
 that compiles is pinned here at real width (the 50280x1024 mamba2-370m
-embedding leaf, a 4-worker gather for the decode-sums), plus a whole train
-step of each wire ``chip_smoke.py`` drives, at smoke size. The Golomb
-kernels have no Mosaic lowering yet and are left out (ROADMAP S2).
+embedding leaf, a 4-worker gather for the decode-sums and the fused decode
++ update), plus a whole train step of each wire ``chip_smoke.py`` drives, at
+smoke size. The Golomb kernels have no Mosaic lowering yet and are left out
+(ROADMAP S2).
 
 The topology is described inside a module fixture, never at import: only one
 process may load the TPU library, and it keeps it until it exits.
@@ -65,7 +66,9 @@ def _kernel_case(name, one_chip):
     from repro.kernels.sparsign.ops import sparsign_op
     from repro.kernels.sparsign_pack2bit.ops import sparsign_pack2bit_op
     from repro.kernels.ternary import ops as ternary
-    from repro.kernels.vote_update.ops import vote_update_op, weighted_vote_update_op
+    from repro.kernels.vote_update.ops import (vote_update_op,
+                                               vote_update_packed2bit_op,
+                                               weighted_vote_update_op)
 
     def s(shape, dtype):
         return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
@@ -102,6 +105,10 @@ def _kernel_case(name, one_chip):
         "vote_update": (
             lambda w, v, e: vote_update_op(w, v, e, quorum=1, interpret=False),
             (g16, s(LEAF, jnp.int8), scalar)),
+        "vote_update_packed2bit": (
+            lambda w, p, e: vote_update_packed2bit_op(w, p, e, quorum=1,
+                                                      interpret=False),
+            (g16, packed, scalar)),
         "weighted_vote_update": (
             lambda w, v, t, e: weighted_vote_update_op(w, v, t, e, q_frac=0.5,
                                                        interpret=False),
@@ -113,7 +120,8 @@ def _kernel_case(name, one_chip):
 KERNELS = ["sparsign", "sparsign_bf16", "sparsign_pack2bit", "sign_pack2bit",
            "noisy_sign_pack2bit", "stochastic_ternary", "qsgd8_pack8",
            "pack2bit", "unpack2bit", "unpack2bit_sum", "unpack2bit_wsum",
-           "unpack8_sum", "vote_update", "weighted_vote_update", "ef_server"]
+           "unpack8_sum", "vote_update", "vote_update_packed2bit",
+           "weighted_vote_update", "ef_server"]
 
 
 @pytest.mark.parametrize("name", KERNELS)
@@ -123,9 +131,11 @@ def test_kernel_compiles_for_v5e(name, one_chip):
     assert "tpu_custom_call" in compiled.as_text()
 
 
-def _simple_step(mesh, backend, *, server="majority_vote", vote_impl="allgather_packed"):
+def _simple_step(mesh, backend, *, server="majority_vote", vote_impl="allgather_packed",
+                 **step_kw):
     """(step, state shapes, batch shapes) of the simple trainer on the smoke
-    mamba2-370m, the batch split over the data axis."""
+    mamba2-370m, the batch split over the data axis; ``step_kw`` goes to
+    ``TrainStepConfig``."""
     from repro.configs.registry import get_config
     from repro.core.algorithm import CompressionConfig
     from repro.models.model import Model
@@ -136,7 +146,7 @@ def _simple_step(mesh, backend, *, server="majority_vote", vote_impl="allgather_
     comp = CompressionConfig(compressor="sparsign", server=server)
     step = build_train_step(model, TrainStepConfig(
         compression=comp, lr=LrSchedule(base=1e-3), vote_impl=vote_impl,
-        backend=backend), mesh)
+        backend=backend, **step_kw), mesh)
     state = jax.eval_shape(lambda: init_state(model.init(jax.random.PRNGKey(0)),
                                               server=server, seed=0))
     rep = NamedSharding(mesh, P())
@@ -204,7 +214,9 @@ def _instructions(hlo_text):
 def test_train_step_scopes_on_v5e(workers, topo):
     """Compiled for a v5e, every kernel and collective of the simple
     trainer's step sits under its layer's scope, and the kernels keep the
-    instruction names the benchmark's name patterns find."""
+    instruction names the benchmark's name patterns find. Over the
+    monolithic gather each leaf's server is the one fused decode + update
+    kernel, and the exchange scope holds the all-gather alone."""
     from repro.launch.mesh import make_mesh
 
     layer_of = _bench_scopes().layer_of
@@ -222,13 +234,19 @@ def test_train_step_scopes_on_v5e(workers, topo):
             assert layer == want, (name, op_name)
             assert any(p.search(name) for p in patterns[want]), name
             seen[want] += 1
+            if want == "server":
+                assert name.startswith("%vote_update_packed2bit"), name
         elif opcode in ("all-gather", "all-gather-start"):
             assert layer == "exchange", (name, op_name)
             seen["exchange"] += 1
         elif "reduce" in name and layer == "counters":
             seen["counters"] += 1
     leaves = 16   # the smoke mamba2's parameter leaves
-    assert seen["uplink"] == leaves and seen["server"] == 2 * leaves
+    assert seen["uplink"] == leaves and seen["server"] == leaves
+    exchange_ops = {opcode for _, opcode, op_name in ins
+                    if layer_of(op_name) == "exchange"}
+    assert exchange_ops <= {"all-gather", "all-gather-start", "all-gather-done"}, \
+        exchange_ops
     assert seen["exchange"] == (leaves if workers > 1 else 0)
     assert seen["counters"] >= 1   # the nonzero count over the payloads
 
@@ -254,15 +272,22 @@ def test_innermost_scope_rule():
 def test_step_scopes_on_cpu():
     """Compiled on the CPU with the jnp backend, the step's operations take
     their layers by the innermost rule: the backward (``transpose(jvp())``)
-    and the rematerialised forward (``checkpoint``) fall to ``fwd_bwd``, the
-    decode-sum called inside the exchange to ``server``."""
+    and the rematerialised forward (``checkpoint``) fall to ``fwd_bwd``; the
+    fused path's decode + update runs under ``server`` alone, and the
+    bucketed wire's decode-sum, called inside the exchange, falls to
+    ``server`` too."""
     from repro.launch.mesh import make_mesh
 
     layer_of = _bench_scopes().layer_of
     mesh = make_mesh((1, 1), ("data", "model"), devices=jax.devices()[:1])
-    step, state, batch = _simple_step(mesh, "jnp")
-    with jax.sharding.set_mesh(mesh):
-        names = {n for _, _, n in _instructions(step.lower(state, batch).compile().as_text())}
+
+    def op_names(**step_kw):
+        step, state, batch = _simple_step(mesh, "jnp", **step_kw)
+        with jax.sharding.set_mesh(mesh):
+            hlo = step.lower(state, batch).compile().as_text()
+        return {n for _, _, n in _instructions(hlo)}
+
+    names = op_names()
     layers = {}
     for n in names:
         layers.setdefault(layer_of(n), []).append(n)
@@ -271,5 +296,8 @@ def test_step_scopes_on_cpu():
     remat = [n for n in names if "/checkpoint/" in n]
     assert backward and remat
     assert {layer_of(n) for n in backward + remat} == {"fwd_bwd"}
-    decode = [n for n in names if "/exchange/server/" in n]
+    assert not [n for n in names if "/exchange/server/" in n]
+    server = [n for n in names if "/server/" in n]
+    assert server and {layer_of(n) for n in server} == {"server"}
+    decode = [n for n in op_names(bucketed=True) if "/exchange/server/" in n]
     assert decode and {layer_of(n) for n in decode} == {"server"}

@@ -86,6 +86,30 @@ def test_packed_decode_sum_no_int8_hbm_intermediate():
     assert unfused >= 4 * 4096
 
 
+def test_fused_server_no_vote_sum_in_hbm():
+    """The fused decode + update writes no int32 vote sum and no int8 copy of
+    it; the chain it replaces writes both, each as large as the leaf."""
+    from repro.kernels.vote_update.ops import vote_update_op
+    n, shape = 4096, (64, 64)
+    gathered = jnp.stack([pack2bit_op(jnp.asarray(
+        np.random.RandomState(s).randint(-1, 2, shape), jnp.int8)) for s in range(4)])
+    w = jnp.asarray(np.random.RandomState(9).randn(*shape), jnp.bfloat16)
+    comp = CompressionConfig(compressor="sparsign", server="majority_vote")
+
+    def fused(g, p):
+        return engine.server_apply_packed(p, g, comp, lr=0.01, backend="interpret")
+
+    def chain(g, p):
+        votes = unpack2bit_sum_op(g, n, shape).astype(jnp.int8)
+        return vote_update_op(p, votes, 0.01)
+
+    for count in (common.int32_hbm_elems, common.int8_hbm_elems):
+        assert count(fused, gathered, w) < n, count   # scalars only
+        assert count(chain, gathered, w) >= n, count
+    assert np.array_equal(np.asarray(jax.jit(fused)(gathered, w)),
+                          np.asarray(jax.jit(chain)(gathered, w)))
+
+
 # ---------------------------------------------------------------------------
 # VoteWire construction + ledger
 # ---------------------------------------------------------------------------
@@ -269,6 +293,53 @@ def test_simple_step_wires_bitwise_equal_single_device():
     # (M=1: both ring collectives move zero bytes)
     assert float(m["wire_bytes_per_device"]) == 0.0
     assert float(m_ref["wire_bytes_per_device"]) == 0.0
+
+
+@pytest.mark.parametrize("backend", ["jnp", OTHER])
+def test_fused_server_step_matches_ring_bitwise(backend):
+    """The benchmark's method (sparsign, majority vote, monolithic 2-bit
+    gather) updates every coordinate in the fused decode + update kernel;
+    the ring keeps the decode-sum and the vote update apart. One step of
+    each gives bitwise the same parameters."""
+    from repro.launch.mesh import make_host_mesh
+    model = _tiny_model()
+    mesh = make_host_mesh(1, 1)
+    params = model.init(jax.random.PRNGKey(0))
+    batch = _tiny_batch(model.cfg.vocab_size)
+    comp = CompressionConfig(compressor="sparsign",
+                             budget=BudgetConfig(kind="fixed", value=1.0),
+                             server="majority_vote")
+    fused, m_fused = _one_step(model, params, batch, mesh, comp=comp,
+                               vote_impl="allgather_packed", backend=backend)
+    ring, m_ring = _one_step(model, params, batch, mesh, comp=comp,
+                             vote_impl="allgather_packed", backend=backend,
+                             ring_chunk_rows=32)
+    assert float(m_fused["server_fused_share"]) == 1.0
+    assert float(m_ring["server_fused_share"]) == 0.0
+    moved = any(not np.array_equal(a, np.asarray(b)) for a, b in zip(
+        jax.tree_util.tree_leaves(fused), jax.tree_util.tree_leaves(params)))
+    assert moved, "the step must actually update params"
+    for (ka, a), (_, b) in zip(jax.tree_util.tree_flatten_with_path(ring)[0],
+                               jax.tree_util.tree_flatten_with_path(fused)[0]):
+        assert np.array_equal(a, b), (backend, jax.tree_util.keystr(ka))
+
+
+@pytest.mark.parametrize("kw", [
+    {"bucketed": True},
+    {"participation": collectives.ParticipationSpec(q_frac=1.0)},
+    {"vote_impl": "psum"},
+    {"comp": CompressionConfig(compressor="sparsign", server="scaled_sign_ef")},
+], ids=["bucketed", "elastic", "psum", "scaled_sign_ef"])
+def test_server_fused_share_off_the_monolithic_gather(kw):
+    """Wires that need the decoded vote sum, and servers other than the
+    majority vote, keep the decode-sum + update chain: the share reads 0."""
+    from repro.launch.mesh import make_host_mesh
+    model = _tiny_model()
+    mesh = make_host_mesh(1, 1)
+    params = model.init(jax.random.PRNGKey(0))
+    kw = {"vote_impl": "allgather_packed", "backend": "jnp", **kw}
+    _, m = _one_step(model, params, _tiny_batch(model.cfg.vocab_size), mesh, **kw)
+    assert float(m["server_fused_share"]) == 0.0
 
 
 @pytest.mark.parametrize("compressor,server", [
